@@ -53,6 +53,10 @@ def main():
     print(f"\ncluster utilization now: {platform.cluster.utilization():.0%}")
     print(f"results in object store: "
           f"{platform.objstore.list('results', train)[:3]} ...")
+    failed = [j for j in (sim, train)
+              if client.status(j) != JobStatus.COMPLETED]
+    if failed:
+        raise SystemExit(f"jobs did not complete: {failed}")
 
 
 if __name__ == "__main__":

@@ -192,6 +192,7 @@ class RealLearner:
         from repro.data.pipeline import DataConfig, SyntheticLM
         from repro.models import steps as msteps
         from repro.optim import adamw
+        from repro.utils import tree_count
 
         m = self.ctx.manifest
         t = m.train
@@ -227,6 +228,8 @@ class RealLearner:
         else:
             self._state = msteps.init_train_state(
                 cfg, jax.random.key(int(t.get("seed", 0))))
+        self.ctx.log(f"model {cfg.name}: "
+                     f"{tree_count(self._state.params)} parameters")
 
     def start(self, resume: bool = False):
         self.phase = "DOWNLOADING"
@@ -257,16 +260,17 @@ class RealLearner:
             self.ctx.set_status("PROCESSING", {"step": self.step})
             return
         if self.phase == "PROCESSING":
+            import jax
             import numpy as np
             m = self.ctx.manifest
-            last_metrics = None
+            losses = []
             for _ in range(self.steps_per_tick):
                 step = self.step
                 if step >= self.total_steps:
                     break
                 batch = self._data.batch_at(step)
                 self._state, metrics = self._train_step(self._state, batch)
-                last_metrics = (step, metrics)
+                losses.append((step, metrics["loss"]))
                 if (step + 1) % m.checkpoint_interval == 0:
                     loss = float(metrics["loss"])
                     ckpt.save(self._bucket, self._ckpt_prefix, step + 1,
@@ -275,10 +279,11 @@ class RealLearner:
                                          job=self.ctx.job_id, step=step + 1)
             # status/metric sync once per tick (periodic updates, §2) — not
             # per step, so the platform never serializes the device queue.
-            if last_metrics is not None:
-                step, metrics = last_metrics
-                loss = float(metrics["loss"])
+            values = jax.device_get([loss for _, loss in losses])
+            for (step, _), loss in zip(losses, values):
+                loss = float(loss)
                 self.loss_history.append((step, loss))
+                self.ctx.log(f"step {step + 1} loss {loss!r}")
                 if not np.isfinite(loss):
                     self.ctx.set_status("FAILED", {"error": "nan loss"})
                     self.ctx.write_exit(2, "non-finite loss")

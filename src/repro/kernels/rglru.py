@@ -14,6 +14,11 @@ the tile's time steps — pure VPU work, no MXU — and writes the tile's
 outputs. HBM traffic is exactly one read of (a, b) and one write of h:
 bandwidth-optimal for a memory-bound op.
 
+Time is the sublane (second-minor) axis of a tile, and the TPU compiler
+loads or stores at a loop-dependent time offset only in whole sublane tiles
+(8 rows of 32-bit, 16 of bf16). So the scan walks ``rows`` time steps per
+load and store, and steps through them with static slices.
+
 Width/batch tiles are (8, 128)-lane aligned. Validated against ``ref.py``
 in interpret mode (tests/test_kernels.py).
 """
@@ -29,7 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, carry_ref, *,
-                  block_t):
+                  block_t, rows):
     """Refs: a/b/o: (block_b, block_t, block_w); h0/hlast: (block_b, block_w);
     carry_ref: VMEM scratch (block_b, block_w) fp32 persisting across the
     sequential time-block walk."""
@@ -39,16 +44,18 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, hlast_ref, carry_ref, *,
     def _init():
         carry_ref[...] = h0_ref[...].astype(jnp.float32)
 
-    h = carry_ref[...]
-    a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
-
-    def step(t, h):
-        h = a[:, t, :] * h + b[:, t, :]
-        o_ref[:, t, :] = h.astype(o_ref.dtype)
+    def chunk(c, h):
+        ts = pl.ds(pl.multiple_of(c * rows, rows), rows)
+        a = a_ref[:, ts, :].astype(jnp.float32)
+        b = b_ref[:, ts, :].astype(jnp.float32)
+        out = []
+        for t in range(rows):
+            h = a[:, t, :] * h + b[:, t, :]
+            out.append(h)
+        o_ref[:, ts, :] = jnp.stack(out, axis=1).astype(o_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_t, step, h)
+    h = jax.lax.fori_loop(0, block_t // rows, chunk, carry_ref[...])
     carry_ref[...] = h
 
     num_t = pl.num_programs(2)
@@ -73,11 +80,12 @@ def rglru_scan_tpu(a, b, h0=None, *, block_b=8, block_t=256, block_w=128,
     block_b = min(block_b, bsz)
     block_t = min(block_t, s)
     block_w = min(block_w, w)
-    if bsz % block_b or s % block_t or w % block_w:
+    rows = min(block_t, 32 // a.dtype.itemsize)  # one sublane tile of a
+    if bsz % block_b or s % block_t or w % block_w or block_t % rows:
         raise ValueError(f"dims must divide blocks: {(bsz, s, w)} vs "
-                         f"{(block_b, block_t, block_w)}")
+                         f"{(block_b, block_t, block_w)}, {rows} rows")
     grid = (bsz // block_b, w // block_w, s // block_t)
-    kernel = functools.partial(_rglru_kernel, block_t=block_t)
+    kernel = functools.partial(_rglru_kernel, block_t=block_t, rows=rows)
     h, hlast = pl.pallas_call(
         kernel,
         grid=grid,

@@ -35,7 +35,6 @@ from repro.launch.mesh import (
     HBM_BW,
     ICI_BW,
     PEAK_FLOPS_BF16,
-    compat_cost_analysis,
     make_env,
     make_production_mesh,
 )
@@ -183,7 +182,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool = False,
         compiled = lowered.compile()
     t_compile = time.time() - t0
 
-    cost = compat_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     # Loop-aware accounting (XLA's cost_analysis counts while bodies once —

@@ -8,6 +8,7 @@ dryrun.py is allowed to force 512 host devices).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.parallel.sharding import (
     MULTI_POD_RULES,
@@ -22,35 +23,11 @@ HBM_BW = 819e9                # bytes/s per chip
 ICI_BW = 50e9                 # bytes/s per link
 
 
-def compat_make_mesh(shape, axes, **kwargs):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and the
-    AxisType enum itself) only exist in newer jax; older ones default to
-    Auto semantics anyway, so omit the argument there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs.setdefault("axis_types", (axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes, **kwargs)
-
-
-def compat_cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` across jax versions: older jax returns
-    a per-computation list, newer a flat dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
-
-
-def make_test_mesh(data: int = 2, model: int = 2):
-    """Small mesh for CPU integration tests (requires >= data*model devices)."""
-    return compat_make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_env(mesh, overrides: dict | None = None) -> MeshEnv:

@@ -37,9 +37,10 @@ class ServeEngine:
                  mesh: Optional[str] = None, ctx_parallel: bool = False,
                  seed: int = 0):
         import jax
+        from jax.sharding import AxisType
 
         from repro.configs import get_config, get_tiny_config
-        from repro.launch.mesh import compat_make_mesh, make_env
+        from repro.launch.mesh import make_env
         from repro.launch.train import parse_mesh
         from repro.models import steps
         from repro.parallel import null_env, use_env
@@ -48,7 +49,8 @@ class ServeEngine:
         self.cfg = get_tiny_config(arch) if tiny else get_config(arch)
         mesh_shape = parse_mesh(mesh) if mesh is not None else None
         if mesh_shape is not None:
-            m = compat_make_mesh(mesh_shape, ("data", "model"))
+            m = jax.make_mesh(mesh_shape, ("data", "model"),
+                              axis_types=(AxisType.Auto,) * 2)
             overrides = {"kv_seq": "model"} if ctx_parallel else {}
             self.env = make_env(m, overrides=overrides)
         else:
@@ -145,6 +147,9 @@ def main():
 
     import jax
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     engine = ServeEngine(args.arch, tiny=args.tiny, mesh=args.mesh,
                          ctx_parallel=args.ctx_parallel, seed=args.seed)
     B, S = args.requests, args.prompt_len

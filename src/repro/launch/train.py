@@ -29,6 +29,41 @@ def parse_mesh(spec: str):
     return tuple(parts)
 
 
+def jit_train_step(cfg, opt_cfg, env, batch: int, seq: int):
+    """The launcher's jitted train step, donating the state.
+
+    Without a mesh the step runs on the default device. With one, the
+    params are placed by the logical rules, the optimizer state by
+    ZeRO-1 and the batch over ``data``. Returns ``(step, state_shardings,
+    batch_shardings)``; both shardings are ``None`` without a mesh. Call
+    it, and the step, inside ``use_env(env)``.
+    """
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.models import steps
+    from repro.models.steps import TrainState
+    from repro.parallel import logical_to_spec, param_shardings
+    from repro.parallel.zero import opt_state_shardings
+
+    train_step = steps.make_train_step(cfg, opt_cfg)
+    if env.mesh is None:
+        return jax.jit(train_step, donate_argnums=(0,)), None, None
+    mesh = env.mesh
+    aparams = steps.abstract_params(cfg)
+    axes = steps.param_axes(cfg)
+    st_sh = TrainState(
+        step=NamedSharding(mesh, P()),
+        params=param_shardings(axes, aparams, env),
+        opt=opt_state_shardings(axes, aparams, env))
+    tok_sh = NamedSharding(mesh, logical_to_spec(("batch", None), env,
+                                                 (batch, seq)))
+    b_sh = {"tokens": tok_sh, "labels": tok_sh}
+    step = jax.jit(train_step, in_shardings=(st_sh, b_sh),
+                   out_shardings=(st_sh, None), donate_argnums=(0,))
+    return step, st_sh, b_sh
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -50,20 +85,20 @@ def main():
     args = ap.parse_args()
 
     import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType
 
     from repro.ckpt import checkpoint as ckpt
     from repro.ckpt.checkpoint import AsyncCheckpointer
     from repro.configs import get_config, get_tiny_config
     from repro.data.objectstore import DirBucket
     from repro.data.pipeline import DataConfig, SyntheticLM
-    from repro.launch.mesh import compat_make_mesh, make_env
+    from repro.launch.cache import enable_compile_cache
+    from repro.launch.mesh import make_env
     from repro.models import steps
-    from repro.models.steps import TrainState
     from repro.optim import adamw
-    from repro.parallel import logical_to_spec, param_shardings, use_env
-    from repro.parallel.zero import opt_state_shardings
+    from repro.parallel import use_env
 
+    enable_compile_cache()
     cfg = get_tiny_config(args.arch) if args.tiny else get_config(args.arch)
     cfg = cfg.replace(remat=args.remat)
     opt_cfg = adamw.AdamWConfig(lr=args.lr, warmup_steps=args.warmup,
@@ -79,7 +114,8 @@ def main():
                 f"mesh {args.mesh} needs {need} devices, have "
                 f"{jax.device_count()} (set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count={need} for CPU)")
-        mesh = compat_make_mesh(mesh_shape, ("data", "model"))
+        mesh = jax.make_mesh(mesh_shape, ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         overrides = {}
         if args.sp:
             overrides["seq"] = "model"
@@ -97,26 +133,8 @@ def main():
     acp = AsyncCheckpointer(bucket, "ckpt") if bucket else None
 
     with use_env(env):
-        train_step = steps.make_train_step(cfg, opt_cfg)
-        if mesh is not None:
-            aparams = steps.abstract_params(cfg)
-            axes = steps.param_axes(cfg)
-            st_sh = TrainState(
-                step=NamedSharding(mesh, P()),
-                params=param_shardings(axes, aparams, env),
-                opt=opt_state_shardings(axes, aparams, env))
-            b_sh = {
-                "tokens": NamedSharding(mesh, logical_to_spec(
-                    ("batch", None), env, (args.batch, args.seq))),
-                "labels": NamedSharding(mesh, logical_to_spec(
-                    ("batch", None), env, (args.batch, args.seq))),
-            }
-            train_step = jax.jit(train_step, in_shardings=(st_sh, b_sh),
-                                 out_shardings=(st_sh, None),
-                                 donate_argnums=(0,))
-        else:
-            st_sh = None
-            train_step = jax.jit(train_step, donate_argnums=(0,))
+        train_step, st_sh, b_sh = jit_train_step(cfg, opt_cfg, env,
+                                                 args.batch, args.seq)
 
         # resume from the newest valid checkpoint (same contract the
         # platform's RealLearner uses)

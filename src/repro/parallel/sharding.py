@@ -108,11 +108,7 @@ def use_env(env: MeshEnv):
     _ENVS.stack.append(env)
     try:
         if env.mesh is not None:
-            # newer jax: jax.set_mesh(mesh); older jax: the Mesh object is
-            # itself the context manager
-            cm = (jax.set_mesh(env.mesh) if hasattr(jax, "set_mesh")
-                  else env.mesh)
-            with cm:
+            with jax.set_mesh(env.mesh):
                 yield env
         else:
             yield env

@@ -6,7 +6,6 @@ import jax.numpy as jnp
 import pytest
 
 from repro.launch.hlo_cost import analyze
-from repro.launch.mesh import compat_cost_analysis, compat_make_mesh
 
 
 def test_loopfree_dot_flops_match_xla():
@@ -37,7 +36,7 @@ def test_scan_flops_multiply_by_trip_count(L):
     expected = L * 2 * 256 ** 3
     assert abs(mine["flops"] - expected) / expected < 0.02
     # XLA's own count is trip-count-blind (the reason this module exists)
-    ca = compat_cost_analysis(c)
+    ca = c.cost_analysis()
     assert ca["flops"] < mine["flops"] or L == 1
 
 
@@ -63,7 +62,6 @@ def test_scanned_equals_unrolled_model():
 
 
 def test_collectives_counted_with_loop_multiplier():
-    mesh = compat_make_mesh((1,), ("x",))
     # hand-written HLO exercise of the parser instead: collective inside while
     hlo = """
 HloModule test

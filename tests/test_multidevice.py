@@ -12,6 +12,8 @@ import pytest
 @pytest.mark.slow
 def test_sharding_suite_on_8_devices():
     env = dict(os.environ)
+    # the child must never reach for an accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = os.path.abspath(

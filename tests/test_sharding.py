@@ -15,9 +15,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro.launch.mesh import compat_make_mesh, make_env
+from repro.launch.mesh import make_env
 from repro.parallel.sharding import (
     MULTI_POD_RULES,
     SINGLE_POD_RULES,
@@ -39,11 +39,13 @@ needs_real_mesh = pytest.mark.xfail(
 @pytest.fixture(scope="module")
 def env():
     if _HAVE_DEVICES:
-        mesh = compat_make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
     else:
         # same topology, no devices: enough for every spec-derivation
         # path (they only read mesh.shape / axis sizes)
-        mesh = jax.sharding.AbstractMesh((("data", 2), ("model", 2)))
+        mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"),
+                                         axis_types=(AxisType.Auto,) * 2)
     return make_env(mesh)
 
 
@@ -172,7 +174,8 @@ def test_elastic_restore_onto_different_mesh(env):
         ckpt.save(bucket, "run", 2, st)
 
     # node failure → restart on a DIFFERENT mesh shape
-    mesh_b = compat_make_mesh((4, 1), ("data", "model"))
+    mesh_b = jax.make_mesh((4, 1), ("data", "model"),
+                           axis_types=(AxisType.Auto,) * 2)
     env_b = make_env(mesh_b)
     with use_env(env_b):
         sh_b = shardings_for(env_b)
