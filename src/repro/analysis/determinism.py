@@ -54,6 +54,9 @@ DET_ALLOWLIST = {
     "src/repro/api/cli.py":
         "operator-facing CLI: startup polling and timeouts are real "
         "time by definition",
+    "src/repro/obs/spans.py":
+        "wall-clock edge: program spans time host work for operators and "
+        "the profiler; no reading feeds simulated state",
 }
 
 _CLOCK_CALLS = {

@@ -125,7 +125,9 @@ from repro.obs import (
     UsageMeter,
     format_comment,
     format_event,
+    gc_pause_totals,
     iter_sse,
+    phase_histograms,
     render_metrics,
 )
 
@@ -1141,6 +1143,14 @@ class ApiHttpServer:
         else:
             limited = {t: row["throttled_429s"] for t, row in usage.items()
                        if row["throttled_429s"]}
+        # tick phases and GC pauses come from this process's span recorder
+        shard_ids = {b.shard_id for b in backends}
+        phases = [({"shard": sh, "phase": ph}, h)
+                  for (sh, ph), h in sorted(phase_histograms().items(),
+                                            key=lambda kv: str(kv[0]))
+                  if sh in shard_ids]
+        gc_pause = [({"generation": str(g)}, s)
+                    for g, s in sorted(gc_pause_totals().items())]
         with self._metrics_lock:
             reqs = dict(self.route_requests)
             lat = dict(self.route_latency)
@@ -1165,6 +1175,12 @@ class ApiHttpServer:
             ("ffdl_deadline_exceeded_total", "counter",
              "Verb/tick deadline overruns recorded against the shard",
              ddl),
+            ("ffdl_tick_phase_seconds", "histogram",
+             "Time of each phase of the shard's scheduling round",
+             phases),
+            ("ffdl_gc_pause_seconds_total", "counter",
+             "Seconds the garbage collector paused the process, by "
+             "generation", gc_pause),
             ("ffdl_events_seq", "gauge",
              "Event-bus high-water sequence number", ev_seq),
             ("ffdl_events_dropped_total", "counter",

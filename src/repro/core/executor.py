@@ -28,6 +28,7 @@ from typing import Any, Optional
 
 from repro.ckpt import checkpoint as ckpt
 from repro.core.types import EventLog, JobManifest
+from repro.obs.spans import count, span
 
 
 class JobVolume:
@@ -245,6 +246,13 @@ class RealLearner:
         return int(self._state.step) if self._state is not None else 0
 
     def tick(self):
+        # Spans split the learner's host time for operators and the
+        # profiler: waiting on the device, building batches, enqueueing
+        # steps, reporting, checkpoint stalls.
+        with span("ffdl.learner.tick"):
+            self._tick()
+
+    def _tick(self):
         if self.phase in ("INIT", "DEAD") or self.done:
             return
         if self.phase == "DOWNLOADING":
@@ -265,38 +273,48 @@ class RealLearner:
             m = self.ctx.manifest
             losses = []
             for _ in range(self.steps_per_tick):
-                step = self.step
+                with span("ffdl.learner.wait"):
+                    step = self.step  # reads the previous step's output
                 if step >= self.total_steps:
                     break
-                batch = self._data.batch_at(step)
-                self._state, metrics = self._train_step(self._state, batch)
+                with span("ffdl.learner.feed"):
+                    batch = self._data.batch_at(step)
+                with span("ffdl.learner.dispatch"):
+                    self._state, metrics = self._train_step(self._state,
+                                                            batch)
+                count("learner.steps")
                 losses.append((step, metrics["loss"]))
                 if (step + 1) % m.checkpoint_interval == 0:
-                    loss = float(metrics["loss"])
-                    ckpt.save(self._bucket, self._ckpt_prefix, step + 1,
-                              self._state, {"loss": loss})
+                    with span("ffdl.learner.wait"):
+                        loss = float(metrics["loss"])
+                    with span("ffdl.ckpt.save"):
+                        ckpt.save(self._bucket, self._ckpt_prefix, step + 1,
+                                  self._state, {"loss": loss})
                     self.ctx.events.emit("learner", "checkpoint",
                                          job=self.ctx.job_id, step=step + 1)
             # status/metric sync once per tick (periodic updates, §2) — not
             # per step, so the platform never serializes the device queue.
-            values = jax.device_get([loss for _, loss in losses])
-            for (step, _), loss in zip(losses, values):
-                loss = float(loss)
-                self.loss_history.append((step, loss))
-                self.ctx.log(f"step {step + 1} loss {loss!r}")
-                if not np.isfinite(loss):
-                    self.ctx.set_status("FAILED", {"error": "nan loss"})
-                    self.ctx.write_exit(2, "non-finite loss")
-                    self.done = True
-                    return
-            self.ctx.set_status("PROCESSING", {"step": self.step})
+            with span("ffdl.learner.wait"):
+                values = jax.device_get([loss for _, loss in losses])
+            with span("ffdl.learner.report"):
+                for (step, _), loss in zip(losses, values):
+                    loss = float(loss)
+                    self.loss_history.append((step, loss))
+                    self.ctx.log(f"step {step + 1} loss {loss!r}")
+                    if not np.isfinite(loss):
+                        self.ctx.set_status("FAILED", {"error": "nan loss"})
+                        self.ctx.write_exit(2, "non-finite loss")
+                        self.done = True
+                        return
+                self.ctx.set_status("PROCESSING", {"step": self.step})
             if self.step >= self.total_steps:
                 self.phase = "STORING"
                 self.ctx.set_status("STORING", {"step": self.step})
             return
         if self.phase == "STORING":
-            ckpt.save(self._bucket, self._ckpt_prefix, self.step,
-                      self._state, {"final": True})
+            with span("ffdl.ckpt.save"):
+                ckpt.save(self._bucket, self._ckpt_prefix, self.step,
+                          self._state, {"final": True})
             self._bucket.write(f"{self.ctx.job_id}/model/DONE",
                                json.dumps({"steps": self.step}))
             self.done = True

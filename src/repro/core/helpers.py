@@ -1,4 +1,4 @@
-"""Helper services: log collection and the Training Metrics Service.
+"""Helper services: log collection for the Training Metrics Service.
 
 FfDL §3.2: "The Training Metrics Service is responsible for collecting
 metrics about both the training jobs and FfDL microservices [...] It also
@@ -8,8 +8,8 @@ ElasticSearch/Kibana."
 ``LogCollector`` streams learner log files off the job volume into the
 searchable ``LogIndex`` (the ElasticSearch analogue), with gap-free resume
 after collector crashes (offset bookkeeping — the 'surprisingly challenging'
-§4 lesson). ``MetricsService`` aggregates job metrics and microservice
-failure/recovery counters.
+§4 lesson). The platform's own metrics are the observability plane's
+(``repro.obs``: usage metering, tick spans, ``/metrics``).
 """
 
 from __future__ import annotations
@@ -322,23 +322,3 @@ class LogCollector:
                 self.volume.write(f".collector/offset-{k}", str(len(content)))
         except IOError:
             pass
-
-
-class MetricsService:
-    """Platform-level metrics: job throughput, component failure counters,
-    cluster utilization samples."""
-
-    def __init__(self, clock):
-        self.clock = clock
-        self.job_metrics: dict[str, list] = defaultdict(list)
-        self.counters: dict[str, int] = defaultdict(int)
-        self.util_samples: list[tuple[float, float]] = []
-
-    def record_job(self, job_id: str, **metrics):
-        self.job_metrics[job_id].append((self.clock.now(), metrics))
-
-    def bump(self, counter: str, n: int = 1):
-        self.counters[counter] += n
-
-    def sample_utilization(self, util: float):
-        self.util_samples.append((self.clock.now(), util))

@@ -52,6 +52,7 @@ from dataclasses import asdict
 from typing import Optional
 
 from repro.core.types import JobManifest, JobRecord, JobStatus
+from repro.obs.spans import span
 
 
 def _idx_add(lst: list, jid: str):
@@ -146,7 +147,11 @@ class MetaStore:
             yield self
         finally:
             self._batch_depth -= 1
-            self._commit()
+            if self._batch_depth == 0:  # nested exits commit nothing
+                # Inside a tick this is the profiler's WAL phase; a tenant
+                # import's commit shows under the same name as a root.
+                with span("ffdl.tick.wal_flush"):
+                    self._commit()
 
     @classmethod
     def recover(cls, clock, journal_path: str) -> "MetaStore":
@@ -392,6 +397,7 @@ class MetaStore:
         present is replaced, not duplicated.
         """
         self._check()
+        # the commit at this batch's exit is timed as ffdl.tick.wal_flush
         with self.batch():
             for op in snap["ops"]:
                 self._append(op)

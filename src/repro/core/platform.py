@@ -10,9 +10,10 @@ cursor-paginated listings. Crash any single replica and idempotent calls
 still succeed (``benchmarks/api_tier.py`` measures this recovery claim).
 
 This class is the **control plane**: it owns and ticks every microservice:
-chaos → cluster (heartbeats/evictions) → LCM (reconcile) → guardians
-(deploy/monitor) → admission (preemption) → scheduler (gang placement) →
-metrics. Internal lifecycle actions (``_halt_internal``/
+timers → chaos → cluster (heartbeats/evictions) → LCM (reconcile) →
+guardians (deploy/monitor) → admission (preemption) → scheduler (gang
+placement) → WAL group commit → accounting, each under a program span
+(``repro.obs.spans``). Internal lifecycle actions (``_halt_internal``/
 ``_resume_internal``, used by admission preemption and requeue timers)
 bypass the API tier: they must keep working while every gateway replica
 is down.
@@ -53,7 +54,7 @@ from repro.core.admission import AdmissionController
 from repro.core.chaos import ChaosConfig, ChaosMonkey
 from repro.core.cluster import ClusterModel
 from repro.core.executor import JobVolume
-from repro.core.helpers import LogIndex, MetricsService
+from repro.core.helpers import LogIndex
 from repro.core.kvstore import EtcdLike
 from repro.core.lcm import LifecycleManager
 from repro.core.metastore import MetaStore
@@ -66,7 +67,7 @@ from repro.core.types import (
     gang_chips,
 )
 from repro.data.objectstore import ObjectStore
-from repro.obs import DEFAULT_RETENTION, UsageMeter, install_meter
+from repro.obs import DEFAULT_RETENTION, UsageMeter, install_meter, span
 
 
 class FfDLPlatform:
@@ -121,7 +122,6 @@ class FfDLPlatform:
         self.admission = AdmissionController(self, self.events)
         self.lcm = LifecycleManager(self, self.events)
         self.chaos = ChaosMonkey(chaos or ChaosConfig(), self)
-        self.metrics = MetricsService(self.clock)
         self.log_index = LogIndex()
         # -- observability plane (repro.obs): the bus stamps events with
         # their owning tenant (so /v2/events can scope visibility) and the
@@ -250,30 +250,43 @@ class FfDLPlatform:
         # exactly like a gray failure would — the tick thread holds the
         # shard write lock, verbs bound their lock waits by deadline, and
         # Federation.tick's per-shard tick budget frees the ticker itself.
-        self.faults.on("shard.tick", key=self.shard_id)
-        self.ticks += 1
-        self.clock.advance(self.tick_period)
-        self.clock.run_until(self.clock.now())
-        # Group-commit scope: every metastore status flip this round rides
-        # one WAL write+flush at scope exit (durable before tick returns)
-        # instead of one flush per update. User-facing submits come in via
-        # the gateway outside this scope and keep durable-before-ack.
-        with self.meta.batch():
-            self.chaos.tick()
-            self.cluster.tick()
-            self.lcm.tick()
-            for g in list(self.guardians.values()):
-                g.tick()
-            self.admission.tick()
-            self.scheduler.tick()
-        self.metrics.sample_utilization(self.cluster.utilization())
-        self._accrue_chip_seconds()
-        # GC finished guardians
-        for job_id, g in list(self.guardians.items()):
-            if g.stage == "GC_DONE":
-                rec = self.meta.get(job_id)
-                if rec.status in TERMINAL or rec.status == JobStatus.HALTED:
-                    del self.guardians[job_id]
+        # Spans name where the round's host time goes, for operators
+        # (/metrics ffdl_tick_phase_seconds) and in the profiler's trace;
+        # a real learner's device stays idle for all of it.
+        with span("ffdl.tick", shard=self.shard_id):
+            self.faults.on("shard.tick", key=self.shard_id)
+            self.ticks += 1
+            with span("ffdl.tick.timers"):
+                self.clock.advance(self.tick_period)
+                self.clock.run_until(self.clock.now())
+            # Group-commit scope: every metastore status flip this round
+            # rides one WAL write+flush at scope exit (durable before tick
+            # returns) instead of one flush per update. User-facing submits
+            # come in via the gateway outside this scope and keep
+            # durable-before-ack.
+            with self.meta.batch():
+                with span("ffdl.tick.chaos"):
+                    self.chaos.tick()
+                with span("ffdl.tick.cluster"):
+                    self.cluster.tick()
+                with span("ffdl.tick.lcm"):
+                    self.lcm.tick()
+                with span("ffdl.tick.guardians"):
+                    for g in list(self.guardians.values()):
+                        g.tick()
+                with span("ffdl.tick.admission"):
+                    self.admission.tick()
+                with span("ffdl.tick.scheduler"):
+                    self.scheduler.tick()
+            with span("ffdl.tick.accounting"):
+                self._accrue_chip_seconds()
+                # GC finished guardians
+                for job_id, g in list(self.guardians.items()):
+                    if g.stage == "GC_DONE":
+                        rec = self.meta.get(job_id)
+                        if (rec.status in TERMINAL
+                                or rec.status == JobStatus.HALTED):
+                            del self.guardians[job_id]
 
     def run_for(self, sim_seconds: float):
         n = int(sim_seconds / self.tick_period)

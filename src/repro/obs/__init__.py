@@ -1,5 +1,5 @@
 # The observability plane (FfDL §4): the sensor layer the platform's
-# operators — human and autonomous — read. Five parts:
+# operators — human and autonomous — read. Six parts:
 #   * bus:     per-shard, sequence-numbered, retention-bounded event bus
 #              (promoted from core.types.EventLog) with tenant-scoped
 #              visibility, served as GET /v2/events with cursor replay;
@@ -12,7 +12,10 @@
 #              --follow` (long-poll remains the fallback contract);
 #   * operator: the autonomous reconciler (shard autoscaling, hot-tenant
 #              isolation, health-gated rolling upgrades) closing the loop
-#              over the sensors above via the /v2/admin verbs.
+#              over the sensors above via the /v2/admin verbs;
+#   * spans:   program spans and counters inside the platform's tick and
+#              the learner, on the profiler's clock, kept per tick in a
+#              bounded ring and served via /metrics (tick phases, GC).
 from repro.obs.bus import (
     DEFAULT_RETENTION,
     Event,
@@ -32,6 +35,15 @@ from repro.obs.metrics import (
     Histogram,
     METRIC_NAMES,
     render_metrics,
+)
+from repro.obs.spans import (
+    RING_TICKS,
+    TickRecord,
+    count,
+    gc_pause_totals,
+    phase_histograms,
+    recent_ticks,
+    span,
 )
 from repro.obs.sse import (
     SSE_CONTENT_TYPE,
@@ -53,14 +65,21 @@ __all__ = [
     "OperatorConfig",
     "OperatorPolicy",
     "PLATFORM_EVENT_KINDS",
+    "RING_TICKS",
     "SSE_CONTENT_TYPE",
     "SseMessage",
+    "TickRecord",
     "USAGE_FIELDS",
     "UsageMeter",
+    "count",
     "event_to_wire",
     "format_comment",
     "format_event",
+    "gc_pause_totals",
     "install_meter",
     "iter_sse",
+    "phase_histograms",
+    "recent_ticks",
     "render_metrics",
+    "span",
 ]

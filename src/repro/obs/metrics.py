@@ -8,7 +8,8 @@ client library. Three instrument shapes:
     monitoring reads: they tolerate torn values across families rather
     than taking every shard lock);
   * :class:`Histogram` is the one stateful instrument — cumulative
-    buckets + sum + count, used for per-route request latency.
+    buckets + sum + count, used for per-route request latency and the
+    tick phases' times (``repro.obs.spans``).
 
 ``METRIC_NAMES`` pins the family names as wire contract (docs/api.md and
 docs/architecture.md map each to its source; tests/test_docs_api.py
@@ -36,6 +37,8 @@ METRIC_NAMES = (
     "ffdl_wal_flushes_total",
     "ffdl_breaker_state",
     "ffdl_deadline_exceeded_total",
+    "ffdl_tick_phase_seconds",
+    "ffdl_gc_pause_seconds_total",
     "ffdl_events_seq",
     "ffdl_events_dropped_total",
     "ffdl_migrations",
