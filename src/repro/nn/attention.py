@@ -1,12 +1,13 @@
 """GQA attention: train/prefill (blocked-causal flash), decode (KV cache),
 local-window (RecurrentGemma), bidirectional (encoder) and cross attention.
 
-The blocked-causal implementation mirrors the structure of the Pallas flash
-kernel in ``repro.kernels.flash_attention`` (same block decomposition, online
-softmax) so that the CPU dry-run lowers an HLO whose FLOP/byte profile is
-representative of the TPU kernel: only lower-triangle (q_block, kv_block)
-pairs are computed, giving ~2x FLOP savings over naive causal attention and
-O(S·C) live memory instead of O(S^2).
+Train and prefill attention goes through ``kernels.ops.self_attention``:
+on a TPU, causal attention over whole kernel blocks runs the splash
+kernel; everywhere else it runs ``flash_attention`` below, a blocked scan
+with the flash kernels' structure (block decomposition, online softmax):
+only lower-triangle (q_block, kv_block) pairs are computed, giving ~2x
+FLOP savings over naive causal attention and O(S·C) live memory instead
+of O(S^2).
 """
 
 from __future__ import annotations
@@ -16,6 +17,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops import self_attention
 from repro.nn import params as prm
 from repro.nn.layers import apply_rope, def_headnorm, headnorm
 from repro.nn.policy import interior_pref
@@ -242,7 +244,8 @@ def gqa_attention(
         o = decode_attention(q, new_cache, cache_len + 1, window=window)
     else:
         if impl == "flash":
-            o = flash_attention(q, k, v, causal=causal, window=window, chunk=chunk)
+            o = self_attention(q, k, v, causal=causal, window=window,
+                               chunk=chunk)
         else:
             o = naive_attention(q, k, v, causal=causal, window=window)
         if mode == "prefill":

@@ -1,13 +1,15 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode on CPU), with shape/
 dtype sweeps as required — plus the chunked-jnp fallback paths."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
-from repro.kernels.ops import flash_attention, rglru_scan
+from repro.kernels import ref, splash
+from repro.kernels.ops import flash_attention, rglru_scan, self_attention
 from repro.nn.attention import flash_attention as chunked_attn
 from repro.nn.attention import naive_attention
 
@@ -67,6 +69,71 @@ def test_flash_q_offset_decode_suffix():
                               force="interpret")
     np.testing.assert_allclose(np.asarray(out_off), np.asarray(out_full),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_splash_kernel_matches_naive_forward_and_backward(dtype):
+    """The train step's TPU attention: causal GQA output and dq, dk, dv."""
+    q, k, v = _qkv(1, 4, 2, 256, 64, dtype)
+    ct = jax.random.normal(jax.random.key(7), q.shape, jnp.float32)
+
+    def kernel(q, k, v):
+        scaled = (q * q.shape[-1] ** -0.5).astype(q.dtype)
+        return splash.causal_attention(scaled, k, v, block=128,
+                                       interpret=True)
+
+    def oracle(q, k, v):
+        return naive_attention(*(t.astype(jnp.float32) for t in (q, k, v)))
+
+    def loss(attn, q, k, v):
+        return jnp.sum(attn(q, k, v).astype(jnp.float32) * ct)
+
+    out = kernel(q, k, v)
+    assert out.dtype == dtype and out.shape == q.shape
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    outs = [(out, oracle(q, k, v))]
+    outs += zip(jax.grad(functools.partial(loss, kernel), (0, 1, 2))(q, k, v),
+                jax.grad(functools.partial(loss, oracle), (0, 1, 2))(q, k, v))
+    for got, want in outs:
+        got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+        # within tol of the largest element: bf16 rounds each operand
+        assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+
+
+ROUTES = [
+    # (causal, window, S, sharded, routed to the kernel on a TPU)
+    (True, 0, 256, False, True),
+    (False, 0, 256, False, False),   # bidirectional (encoder)
+    (True, 64, 256, False, False),   # local window
+    (True, 0, 192, False, False),    # not a multiple of a kernel block
+    (True, 0, 256, True, False),     # under a mesh: no auto-partitioning
+]
+
+
+@pytest.mark.parametrize("causal,window,s,sharded,kernel", ROUTES)
+def test_self_attention_routes_to_the_kernel_or_the_scan(causal, window, s,
+                                                         sharded, kernel):
+    from repro.obs.spans import ROOT, recent_ticks, span
+    from repro.parallel import MeshEnv, null_env, use_env
+
+    q, k, v = _qkv(1, 4, 2, s, 64, jnp.float32)
+    attn = functools.partial(self_attention, causal=causal, window=window,
+                             chunk=64)
+    env = (MeshEnv(jax.make_mesh((1,), ("data",)), {"batch": "data"})
+           if sharded else null_env())
+    with use_env(env):
+        with span(ROOT):
+            jaxpr = str(jax.make_jaxpr(attn)(q, k, v))
+        out = jax.jit(attn)(q, k, v)
+    # the kernel is staged only where it may run; the CPU lowers the scan
+    # and counts it so
+    assert ("pallas_call" in jaxpr) == kernel
+    counters = recent_ticks(1)[0].counters
+    assert counters["attention.chunked"] == 1
+    assert "attention.kernel" not in counters
+    want = jax.jit(functools.partial(chunked_attn, causal=causal,
+                                     window=window, chunk=64))(q, k, v)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
 
 
 RGLRU_CASES = [

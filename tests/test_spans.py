@@ -205,13 +205,13 @@ def test_spans_do_not_import_jax():
 
 # -- a platform tick, as the profiler records it ---------------------------
 
-def _platform_with_learner(shard_id):
+def _platform_with_learner(shard_id, seq=32):
     p = FfDLPlatform(n_hosts=1, chips_per_host=1, shard_id=shard_id)
     c = ApiClient.for_platform(p, tenant="spans")
     job = c.submit(JobManifest(name="spans", tenant="spans",
                                arch="smollm-360m", n_learners=1,
                                chips_per_learner=1,
-                               train={"steps": 40, "batch": 2, "seq": 32}))
+                               train={"steps": 40, "batch": 2, "seq": seq}))
     for _ in range(100):
         p.tick()
         g = p.guardians.get(job)
@@ -268,6 +268,17 @@ def test_tick_spans_land_in_the_profilers_trace(tmp_path):
         assert r.total_s("ffdl.learner.tick") <= r.total_s(
             "ffdl.tick.guardians")
         assert r.totals["ffdl.learner.dispatch"].count == 5
+
+
+def test_the_tick_that_builds_the_step_counts_its_attention_path():
+    # 128 tokens fit a kernel block: on the CPU the scan is still the path
+    p, _ = _platform_with_learner("t-attn", seq=128)
+    built = recent_ticks(1)[0]
+    assert built.shard == "t-attn"
+    assert built.counters["attention.chunked"] >= 1
+    assert "attention.kernel" not in built.counters
+    p.tick()  # the step is traced once: later ticks count nothing
+    assert "attention.chunked" not in recent_ticks(1)[0].counters
 
 
 # -- /metrics -------------------------------------------------------------

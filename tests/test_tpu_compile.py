@@ -10,6 +10,7 @@ worker imports this file.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -61,6 +62,11 @@ def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+def _splash_kernels(text: str) -> set:
+    """Names of the splash-attention kernels a compiled program calls."""
+    return set(re.findall(r"%(splash_mqa_[a-z_]+)\.\d+ = ", text))
+
+
 def test_flash_attention_kernel_at_smollm_widths(one_chip):
     cfg = get_config("smollm-360m")
     q = jax.ShapeDtypeStruct((1, cfg.n_heads, TRAIN_SEQ, cfg.hd),
@@ -81,7 +87,9 @@ def test_rglru_kernel_at_recurrentgemma_width(one_chip, dtype):
 
 
 def test_smollm_train_step_fits_one_chip(one_chip):
-    """The step as the platform's learner jits it (no donation)."""
+    """The step as the platform's learner jits it (no donation): its
+    attention is the splash kernel, forward (run again under remat) and
+    backward, and the only loops left are the two layer scans."""
     cfg = get_config("smollm-360m")
     opt = adamw.AdamWConfig(total_steps=15)
     state = _on(one_chip, steps.abstract_train_state(cfg))
@@ -93,6 +101,11 @@ def test_smollm_train_step_fits_one_chip(one_chip):
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert need < V5E_HBM_BYTES, f"{need / 2**30:.2f} GiB"
+    text = compiled.as_text()
+    assert _splash_kernels(text) == {"splash_mqa_fwd_residuals",
+                                     "splash_mqa_dq_no_residuals",
+                                     "splash_mqa_dkv_no_residuals"}
+    assert len(re.findall(r" while\(", text)) == 2
 
 
 @pytest.mark.parametrize("phase", ["prefill", "decode"])
@@ -112,3 +125,6 @@ def test_smollm_serve_steps_compile(one_chip, phase):
         args = (params, tok((1, 1)), states, tok(()))
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
+    if phase == "prefill":  # the kernel's forward over the prompt
+        assert _splash_kernels(compiled.as_text()) == {
+            "splash_mqa_fwd_no_residuals"}
