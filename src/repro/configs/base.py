@@ -26,11 +26,28 @@ class ModelConfig:
     d_ff: int
     vocab_size: int
     head_dim: int = 0  # 0 → d_model // n_heads
-    # MoE
+    # MoE: routing is dropless. The router spans all ``n_experts``; this
+    # program holds ``e_local`` of them (0 → all), from expert 0: a chip's
+    # share under expert parallelism.
     n_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
+    e_local: int = 0
+    shared_d_ff: int = 0  # shared experts, as one always-on MLP (0 → none)
+    router_score: str = "softmax"  # softmax | sigmoid
+    routed_scale: float = 1.0  # on the normalised top-k weights
+    # sigmoid routing's selection bias, not trained by gradients: after
+    # each step bias_i += rate * sign(mean load - load_i) (0 → no bias)
+    router_bias_rate: float = 0.0
+    balance_loss: str = "switch"  # switch | seq (per-sequence, DeepSeek-V3)
+    balance_weight: float = 0.01
+    n_dense_layers: int = 0  # leading layers with a dense MLP of d_ff
+    # multi-head latent attention (kv_lora_rank > 0; no q compression):
+    # per head q/k of qk_nope_dim + qk_rope_dim, v of v_head_dim
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
     # attention details
     qkv_bias: bool = False
     qk_norm: bool = False
@@ -50,6 +67,7 @@ class ModelConfig:
     # misc
     act: str = "silu"
     rms_norm: bool = True
+    rms_eps: float = 1e-6
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     # training-time policy knobs (overridable per run / hillclimb)
@@ -63,6 +81,18 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.e_local or self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers if self.is_moe else 0
 
     @property
     def sub_quadratic(self) -> bool:
@@ -87,12 +117,20 @@ class ModelConfig:
         if not self.tie_embeddings:
             n += self.vocab_size * d
         attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        if self.is_mla:
+            qk = self.qk_nope_dim + self.qk_rope_dim
+            r = self.kv_lora_rank
+            attn = (d * self.n_heads * qk + d * (r + self.qk_rope_dim) + r
+                    + r * self.n_heads * (self.qk_nope_dim + self.v_head_dim)
+                    + self.n_heads * self.v_head_dim * d)
         dense_mlp = 3 * d * self.d_ff if self.act == "silu" else 2 * d * self.d_ff
-        moe_mlp = self.n_experts * 3 * d * self.moe_d_ff + d * self.n_experts
-        for kind in self.pattern_for_layers():
+        moe_mlp = (self.experts_held * 3 * d * self.moe_d_ff
+                   + d * self.n_experts + 3 * d * self.shared_d_ff)
+        for i, kind in enumerate(self.pattern_for_layers()):
             if kind == "attn":
                 n += attn
-                n += moe_mlp if self.is_moe else dense_mlp
+                moe = self.is_moe and i >= self.n_dense_layers
+                n += moe_mlp if moe else dense_mlp
             elif kind == "rglru":
                 w = self.lru_width or d
                 n += 2 * d * w + w * d + 3 * w * (w // max(self.n_heads, 1)) + self.conv_width * w
@@ -117,8 +155,8 @@ class ModelConfig:
             return self.param_count()
         d = self.d_model
         full = self.param_count()
-        moe_total = self.n_layers * self.n_experts * 3 * d * self.moe_d_ff
-        moe_active = self.n_layers * self.top_k * 3 * d * self.moe_d_ff
+        moe_total = self.n_moe_layers * self.experts_held * 3 * d * self.moe_d_ff
+        moe_active = self.n_moe_layers * self.top_k * 3 * d * self.moe_d_ff
         return full - moe_total + moe_active
 
 
@@ -149,6 +187,7 @@ ARCH_IDS = [
     "qwen2.5-3b",
     "chameleon-34b",
     "recurrentgemma-2b",
+    "moonlight-16b-a3b",
 ]
 
 

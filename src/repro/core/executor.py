@@ -206,7 +206,13 @@ class RealLearner:
         opt_cfg = adamw.AdamWConfig(
             lr=t.get("lr", 3e-4), warmup_steps=t.get("warmup", 10),
             total_steps=self.total_steps)
-        self._train_step = jax.jit(msteps.make_train_step(cfg, opt_cfg))
+        # On an accelerator the step takes the old state's buffers for the
+        # new one: at published widths two whole states do not fit on one
+        # chip. The CPU backend, where memory does not bind, leaves the
+        # state it is given valid (a caller may keep it).
+        donate = (0,) if jax.default_backend() != "cpu" else ()
+        self._train_step = jax.jit(msteps.make_train_step(cfg, opt_cfg),
+                                   donate_argnums=donate)
         self._data = SyntheticLM(DataConfig(
             vocab_size=cfg.vocab_size, seq_len=t.get("seq", 128),
             global_batch=t.get("batch", 8), seed=t.get("seed", 0)))
@@ -283,7 +289,9 @@ class RealLearner:
                     self._state, metrics = self._train_step(self._state,
                                                             batch)
                 count("learner.steps")
-                losses.append((step, metrics["loss"]))
+                losses.append((step, metrics["loss"],
+                               {k: metrics[k] for k in MOE_COUNTERS
+                                if k in metrics}))
                 if (step + 1) % m.checkpoint_interval == 0:
                     with span("ffdl.learner.wait"):
                         loss = float(metrics["loss"])
@@ -295,9 +303,11 @@ class RealLearner:
             # status/metric sync once per tick (periodic updates, §2) — not
             # per step, so the platform never serializes the device queue.
             with span("ffdl.learner.wait"):
-                values = jax.device_get([loss for _, loss in losses])
+                values = jax.device_get([(loss, c) for _, loss, c in losses])
             with span("ffdl.learner.report"):
-                for (step, _), loss in zip(losses, values):
+                for (step, _, _), (loss, counters) in zip(losses, values):
+                    for name, n in counters.items():
+                        count(MOE_COUNTERS[name], float(n))
                     loss = float(loss)
                     self.loss_history.append((step, loss))
                     self.ctx.log(f"step {step + 1} loss {loss!r}")
@@ -320,6 +330,11 @@ class RealLearner:
             self.done = True
             self.ctx.set_status("COMPLETED", {"step": self.step})
             self.ctx.write_exit(0)
+
+
+# the step's MoE metrics and the counters they add to, once a tick
+MOE_COUNTERS = {"moe_rows_held": "moe.rows_held",
+                "moe_rows_busiest": "moe.rows_busiest"}
 
 
 def make_learner(ctx: LearnerContext):

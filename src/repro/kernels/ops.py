@@ -8,7 +8,11 @@ interpret mode.
 
 from __future__ import annotations
 
+import functools
+
 import jax
+import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
 from repro.kernels import ref, splash
 from repro.kernels.flash_attention import flash_attention_tpu
@@ -22,7 +26,7 @@ def _on_tpu() -> bool:
 
 
 def self_attention(q, k, v, *, causal=True, window=0, chunk=512):
-    """Train and prefill attention: q (B, H, S, D), k/v (B, KV, S, D).
+    """Train and prefill attention: q/k (B, H|KV, S, D), v (B, KV, S, Dv).
 
     Causal attention over whole sequences of a multiple of a kernel block,
     in an unsharded program, runs the splash kernel (forward and backward)
@@ -54,6 +58,62 @@ def self_attention(q, k, v, *, causal=True, window=0, chunk=512):
         return splash.causal_attention(scaled, k, v, block=block)
 
     return jax.lax.platform_dependent(q, k, v, tpu=kernel, default=scan)
+
+
+def gmm_tiling(m: int, k: int, n: int) -> tuple:
+    """The megablox kernel's (m, k, n) tiles: 512 rows by 1024-wide
+    slices, or the whole dimension where it is smaller; partial tiles of
+    k and n are masked by the kernel."""
+    return min(512, m), min(1024, k), min(1024, n)
+
+
+def expert_ffn(rows, up, gate, down, sizes, act="silu", *,
+               force: str | None = None):
+    """SwiGLU experts over rows grouped by expert.
+
+    rows (M, d) hold expert g's rows at [sum(sizes[:g]), sum(sizes[:g+1]));
+    up/gate (G, d, f), down (G, f, d), sizes (G,) int32. Rows past
+    ``sum(sizes)`` are not computed, and their output is undefined: the
+    caller masks them. bf16 operands, f32 accumulation, output in rows'
+    dtype.
+
+    Where the program is lowered for a TPU, outside a mesh, the three
+    products run as JAX's megablox grouped matmul (``gmm``; its backward
+    is ``gmm`` and ``tgmm``), whose grid covers only the tiles routed;
+    elsewhere as ``jax.lax.ragged_dot``. Counts ``moe.gmm`` or
+    ``moe.dense`` at trace time, by the default backend. ``force="interpret"``
+    runs the kernel in the Pallas interpreter (tests).
+    """
+    from repro.nn.layers import activation
+
+    fn = activation(act)
+
+    def ffn(matmul):
+        def run(rows, up, gate, down, sizes):
+            h = (fn(matmul(rows, gate, sizes).astype(jnp.float32))
+                 * matmul(rows, up, sizes).astype(jnp.float32))
+            return matmul(h.astype(rows.dtype), down, sizes)
+        return run
+
+    def gmm(lhs, rhs, sizes, interpret=False):
+        tiling = gmm_tiling(lhs.shape[0], lhs.shape[1], rhs.shape[2])
+        return megablox.gmm(lhs, rhs, sizes, lhs.dtype, tiling,
+                            interpret=interpret)
+
+    def ragged(lhs, rhs, sizes):
+        return jax.lax.ragged_dot(lhs, rhs, sizes,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(lhs.dtype)
+
+    args = (rows, up, gate, down, sizes)
+    if force == "interpret":
+        return ffn(functools.partial(gmm, interpret=True))(*args)
+    if current_env().active:
+        count("moe.dense")
+        return ffn(ragged)(*args)
+    count("moe.gmm" if _on_tpu() else "moe.dense")
+    return jax.lax.platform_dependent(*args, tpu=ffn(gmm),
+                                      default=ffn(ragged))
 
 
 def flash_attention(q, k, v, *, causal=True, window=0, q_offset=0,

@@ -37,9 +37,10 @@ def block_for(seq: int) -> Optional[int]:
 
 
 def causal_attention(q, k, v, *, block: int, interpret: bool = False):
-    """Causal self-attention: q (B, H, S, D), pre-scaled; k/v (B, KV, S, D).
+    """Causal self-attention: q (B, H, S, D), pre-scaled; k (B, KV, S, D),
+    v (B, KV, S, Dv), whose head width may differ from q's and k's.
 
-    Returns (B, H, S, D) in q's dtype. ``S`` must be a multiple of
+    Returns (B, H, S, Dv) in q's dtype. ``S`` must be a multiple of
     ``block``; ``interpret`` runs the kernels in the Pallas interpreter
     (CPU tests).
     """
@@ -56,4 +57,4 @@ def causal_attention(q, k, v, *, block: int, interpret: bool = False):
                                               interpret=interpret)
     qg = q.reshape(b, n_kv, groups, s, d)
     o = jax.vmap(jax.vmap(kernel))(qg, k, v)  # over batch, then kv heads
-    return o.reshape(b, h, s, d)
+    return o.reshape(b, h, s, v.shape[-1])

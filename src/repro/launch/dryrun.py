@@ -135,7 +135,9 @@ def build_cell(arch: str, shape_name: str, env, remat=None, overrides=None):
         fn = steps.make_train_step(cfg, opt_cfg)
         astate = steps.abstract_train_state(cfg)
         oshard = opt_state_shardings(paxes, aparams, env)
-        st_shard = TrainState(step=ns(P()), params=pshard, opt=oshard)
+        st_shard = TrainState(step=ns(P()), params=pshard, opt=oshard,
+                              buffers=jax.tree.map(lambda _: ns(P()),
+                                                   astate.buffers))
         in_sh = (st_shard, batch_shardings(specs["batch"]))
         out_sh = (st_shard, None)
         return fn, (astate, specs["batch"]), in_sh, out_sh, cfg

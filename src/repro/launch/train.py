@@ -55,7 +55,9 @@ def jit_train_step(cfg, opt_cfg, env, batch: int, seq: int):
     st_sh = TrainState(
         step=NamedSharding(mesh, P()),
         params=param_shardings(axes, aparams, env),
-        opt=opt_state_shardings(axes, aparams, env))
+        opt=opt_state_shardings(axes, aparams, env),
+        buffers=jax.tree.map(lambda _: NamedSharding(mesh, P()),
+                             jax.eval_shape(lambda: steps.init_buffers(cfg))))
     tok_sh = NamedSharding(mesh, logical_to_spec(("batch", None), env,
                                                  (batch, seq)))
     b_sh = {"tokens": tok_sh, "labels": tok_sh}

@@ -25,8 +25,11 @@ def def_lm(cfg: ModelConfig):
 
 
 def lm_apply(p, tokens, cfg: ModelConfig, *, mode="train", states=None,
-             cache_len=None, positions=None):
-    """tokens: (B, S) int32 → (logits (B, S, V) fp32, new_states, aux)."""
+             cache_len=None, positions=None, buffers=None):
+    """tokens: (B, S) int32 → (logits (B, S, V) fp32, new_states, aux).
+
+    ``buffers`` holds state that gradients do not train (the routers'
+    correction biases); aux is ``nn.blocks.stack_apply``'s."""
     if positions is None:
         if mode == "decode":
             positions = jnp.broadcast_to(cache_len, tokens.shape).astype(jnp.int32)
@@ -36,8 +39,8 @@ def lm_apply(p, tokens, cfg: ModelConfig, *, mode="train", states=None,
     x = shard(x, "batch", "seq", "embed")
     x, new_states, aux = stack_apply(p["blocks"], x, cfg, positions=positions,
                                      mode=mode, states=states,
-                                     cache_len=cache_len)
-    x = norm(p["final_norm"], x, cfg.rms_norm)
+                                     cache_len=cache_len, buffers=buffers)
+    x = norm(p["final_norm"], x, cfg.rms_norm, cfg.rms_eps)
     table = p["embed"] if cfg.tie_embeddings else p["unembed"]
     logits = unembed(table, x)
     logits = shard(logits, "batch", "seq", "vocab")

@@ -24,9 +24,9 @@ class TrainState(NamedTuple):
     step: jax.Array  # () int32
     params: dict
     opt: adamw.OptState
-
-
-AUX_WEIGHT = 0.01  # MoE load-balance loss weight
+    # state that gradients do not train: {"router_bias": (MoE layers,
+    # n_experts) f32} for routers with a correction bias, else empty
+    buffers: dict = {}
 
 
 def model_defs(cfg: ModelConfig):
@@ -47,47 +47,88 @@ def param_axes(cfg: ModelConfig):
     return prm.axes_of(model_defs(cfg))
 
 
+def init_buffers(cfg: ModelConfig) -> dict:
+    """The routers' correction biases, zero, where the model has them."""
+    if not (cfg.is_moe and cfg.router_bias_rate):
+        return {}
+    return {"router_bias": jnp.zeros((cfg.n_moe_layers, cfg.n_experts),
+                                     jnp.float32)}
+
+
 def init_train_state(cfg: ModelConfig, key) -> TrainState:
     params = init_params(cfg, key)
-    return TrainState(jnp.zeros((), jnp.int32), params, adamw.init(params))
+    return TrainState(jnp.zeros((), jnp.int32), params, adamw.init(params),
+                      init_buffers(cfg))
 
 
 def abstract_train_state(cfg: ModelConfig) -> TrainState:
     params = abstract_params(cfg)
     return TrainState(jax.ShapeDtypeStruct((), jnp.int32), params,
-                      adamw.abstract_state(params))
+                      adamw.abstract_state(params),
+                      jax.eval_shape(lambda: init_buffers(cfg)))
 
 
 # --------------------------------------------------------------------------
 # Train step
 # --------------------------------------------------------------------------
 
-def loss_fn(params, batch, cfg: ModelConfig):
+def loss_fn(params, batch, cfg: ModelConfig, buffers=None):
+    """Cross-entropy plus ``cfg.balance_weight`` x the MoE balance loss;
+    the parts also carry each MoE layer's routed rows per expert."""
+    load = None
     if cfg.is_encoder_decoder:
         memory = encdec.encode(params, batch["frames"], cfg)
         logits = encdec.decode_train(params, batch["tokens"], memory, cfg)
         aux = jnp.zeros((), jnp.float32)
     else:
-        logits, _, aux = lm.lm_apply(params, batch["tokens"], cfg, mode="train")
+        logits, _, stack_aux = lm.lm_apply(params, batch["tokens"], cfg,
+                                           mode="train", buffers=buffers)
+        aux, load = stack_aux["balance"], stack_aux.get("load")
     ce = lm.cross_entropy(logits, batch["labels"])
-    return ce + AUX_WEIGHT * aux, {"ce": ce, "aux": aux}
+    parts = {"ce": ce, "aux": aux}
+    if load is not None:
+        parts["load"] = load
+    return ce + cfg.balance_weight * aux, parts
+
+
+def update_router_bias(bias, load, rate: float):
+    """bias_i += rate * sign(mean load - load_i), per layer: experts
+    routed less than the mean become likelier to be chosen."""
+    mean = jnp.mean(load, axis=-1, keepdims=True)
+    return bias + rate * jnp.sign(mean - load)
 
 
 def make_train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig):
+    """The step. Its metrics hold, for MoE models, ``moe_rows_held``: rows
+    routed to the experts this program holds, summed over layers, and
+    ``moe_rows_busiest``: the busiest held expert's rows, summed over
+    layers."""
+
     def train_step(state: TrainState, batch):
         (loss, parts), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params, batch, cfg)
+            loss_fn, has_aux=True)(state.params, batch, cfg, state.buffers)
         new_params, new_opt, om = adamw.update(
             opt_cfg, grads, state.opt, state.step, jnp.dtype(cfg.dtype))
+        load = parts.pop("load", None)
+        buffers = state.buffers
+        if load is not None:
+            held = load[:, :cfg.experts_held]
+            parts["moe_rows_held"] = jnp.sum(held)
+            parts["moe_rows_busiest"] = jnp.sum(jnp.max(held, axis=-1))
+            if "router_bias" in buffers:
+                buffers = dict(buffers, router_bias=update_router_bias(
+                    buffers["router_bias"], load, cfg.router_bias_rate))
         metrics = {"loss": loss, **parts, **om, "step": state.step}
-        return TrainState(state.step + 1, new_params, new_opt), metrics
+        return TrainState(state.step + 1, new_params, new_opt,
+                          buffers), metrics
 
     return train_step
 
 
 def make_eval_step(cfg: ModelConfig):
-    def eval_step(params, batch):
-        loss, parts = loss_fn(params, batch, cfg)
+    def eval_step(params, batch, buffers=None):
+        loss, parts = loss_fn(params, batch, cfg, buffers)
+        parts.pop("load", None)
         return {"loss": loss, **parts}
 
     return eval_step

@@ -119,11 +119,13 @@ def flash_attention(q, k, v, *, causal=True, window=0, chunk=512,
     ``window > 0`` restricts each query to the last ``window`` keys (local
     attention). ``q_offset`` is the absolute position of q[0] relative to
     k[0] (used when q is a suffix of the kv sequence).
-    Returns (B, H, S, D) in q.dtype.
+    v's head width may differ from q's and k's. Returns (B, H, S, Dv) in
+    q.dtype.
     """
     b, h, sq, d = q.shape
     n_kv = k.shape[1]
     skv = k.shape[2]
+    dv = v.shape[-1]
     scale = d ** -0.5
     qg = _group_q((q * scale).astype(q.dtype), n_kv)
 
@@ -143,7 +145,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, chunk=512,
             lo = max(0, (q_offset + i * cq - window) // ck)
         m = jnp.full((b, n_kv, h // n_kv, cq), NEG_INF, jnp.float32)
         l = jnp.zeros((b, n_kv, h // n_kv, cq), jnp.float32)
-        o = jnp.zeros((b, n_kv, h // n_kv, cq, d), jnp.float32)
+        o = jnp.zeros((b, n_kv, h // n_kv, cq, dv), jnp.float32)
 
         def body(carry, j):
             m, l, o = carry
@@ -162,7 +164,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, chunk=512,
             return (m, l, o), None
 
         (m, l, o), _ = jax.lax.scan(body, (m, l, o), jnp.arange(lo, hi))
-        outs.append(_finish(m, l, o, q.dtype).reshape(b, h, cq, d))
+        outs.append(_finish(m, l, o, q.dtype).reshape(b, h, cq, dv))
     return jnp.concatenate(outs, axis=2) if len(outs) > 1 else outs[0]
 
 
@@ -182,7 +184,7 @@ def naive_attention(q, k, v, *, causal=True, window=0, q_offset=0):
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bkgsc,bkcd->bkgsd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
-    return o.reshape(b, h, sq, d).astype(q.dtype)
+    return o.reshape(b, h, sq, v.shape[-1]).astype(q.dtype)
 
 
 def decode_attention(q, cache: KVCache, cache_len, *, window=0):

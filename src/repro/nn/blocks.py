@@ -1,7 +1,7 @@
 """Per-layer blocks and the layer-stack machinery.
 
 Block kinds (``cfg.block_pattern`` entries):
-  * ``attn``  — GQA attention (+ dense MLP or MoE FFN)
+  * ``attn``  — GQA or latent attention (+ dense MLP or MoE FFN)
   * ``rglru`` — Griffin recurrent block (+ dense MLP)
   * ``mlstm`` — xLSTM matrix-LSTM block (self-contained up/down projections)
   * ``slstm`` — xLSTM scalar-LSTM block (self-contained gated FFN)
@@ -9,6 +9,11 @@ Block kinds (``cfg.block_pattern`` entries):
 ``stack_*`` drives a homogeneous stack through ``lax.scan`` over stacked
 params (compile-time O(1) in depth — essential for the 94-layer MoE) or an
 unrolled loop for heterogeneous patterns; both honor the remat policy.
+Leading dense layers of an MoE model (``cfg.n_dense_layers``) run unrolled
+in front of the scanned MoE layers.
+
+Blocks return ``(x, new_state, aux)``; aux is ``{}`` or, for an MoE
+layer, ``{"balance": (), "load": (n_experts,)}``.
 """
 
 from __future__ import annotations
@@ -22,6 +27,7 @@ from repro.configs.base import ModelConfig
 from repro.nn import params as prm
 from repro.nn.attention import KVCache, def_gqa, gqa_attention
 from repro.nn.layers import def_norm, norm
+from repro.nn.mla import def_mla, mla_attention
 from repro.nn.mlp import def_mlp, mlp
 from repro.nn.moe import def_moe, moe_ffn
 from repro.nn.policy import interior_pref
@@ -52,15 +58,22 @@ from repro.parallel import shard
 # defs
 # --------------------------------------------------------------------------
 
-def def_attn_block(cfg: ModelConfig):
+def def_attn_block(cfg: ModelConfig, moe: Optional[bool] = None):
+    if cfg.is_mla:
+        attn = def_mla(cfg.d_model, cfg.n_heads, cfg.kv_lora_rank,
+                       cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim)
+    else:
+        attn = def_gqa(cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
+                       cfg.qkv_bias, cfg.qk_norm)
     d = {
         "norm1": def_norm(cfg.d_model, cfg.rms_norm),
-        "attn": def_gqa(cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd,
-                        cfg.qkv_bias, cfg.qk_norm),
+        "attn": attn,
         "norm2": def_norm(cfg.d_model, cfg.rms_norm),
     }
-    if cfg.is_moe:
-        d["moe"] = def_moe(cfg.d_model, cfg.n_experts, cfg.moe_d_ff, cfg.top_k)
+    if cfg.is_moe if moe is None else moe:
+        d["moe"] = def_moe(cfg.d_model, cfg.n_experts, cfg.moe_d_ff,
+                           e_local=cfg.experts_held,
+                           shared_d_ff=cfg.shared_d_ff)
     else:
         d["mlp"] = def_mlp(cfg.d_model, cfg.d_ff, cfg.act)
     return d
@@ -149,6 +162,9 @@ def _group_rms(scale, x, n_heads, eps=1e-6):
 def init_block_state(cfg: ModelConfig, kind: str, batch: int, s_max: int,
                      dtype=jnp.bfloat16, compact: bool = False):
     if kind == "attn":
+        if cfg.is_mla:
+            raise NotImplementedError(
+                f"{cfg.name}: latent attention has no decode cache yet")
         window = cfg.local_window
         # compact=True bounds a local-attention cache at the window (used by
         # the dry-run to size long_500k honestly); executed serving keeps
@@ -185,24 +201,33 @@ def init_block_state(cfg: ModelConfig, kind: str, batch: int, s_max: int,
 # --------------------------------------------------------------------------
 
 def apply_attn_block(p, x, cfg: ModelConfig, *, positions, mode="train",
-                     state=None, cache_len=None):
-    window = cfg.local_window
-    h = norm(p["norm1"], x, cfg.rms_norm)
-    attn_out, new_cache = gqa_attention(
-        p["attn"], h,
-        n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
-        positions=positions, rope_theta=cfg.rope_theta, use_rope=cfg.use_rope,
-        causal=True, window=window, chunk=cfg.attn_chunk,
-        cache=state, cache_len=cache_len, mode=mode,
-    )
+                     state=None, cache_len=None, bias=None):
+    """``bias``: this layer's router correction bias (MoE layers)."""
+    h = norm(p["norm1"], x, cfg.rms_norm, cfg.rms_eps)
+    if cfg.is_mla:
+        attn_out, new_cache = mla_attention(
+            p["attn"], h, qk_nope_dim=cfg.qk_nope_dim,
+            qk_rope_dim=cfg.qk_rope_dim, positions=positions,
+            rope_theta=cfg.rope_theta, eps=cfg.rms_eps,
+            chunk=cfg.attn_chunk), None
+    else:
+        attn_out, new_cache = gqa_attention(
+            p["attn"], h,
+            n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd,
+            positions=positions, rope_theta=cfg.rope_theta,
+            use_rope=cfg.use_rope, causal=True, window=cfg.local_window,
+            chunk=cfg.attn_chunk, cache=state, cache_len=cache_len, mode=mode,
+        )
     x = x + attn_out
     x = shard(x, "batch", "seq", "embed")
-    h = norm(p["norm2"], x, cfg.rms_norm)
-    if cfg.is_moe:
-        ffn_out, aux = moe_ffn(p["moe"], h, top_k=cfg.top_k,
-                               capacity_factor=cfg.capacity_factor, act=cfg.act)
+    h = norm(p["norm2"], x, cfg.rms_norm, cfg.rms_eps)
+    if "moe" in p:
+        ffn_out, aux = moe_ffn(p["moe"], h, top_k=cfg.top_k, act=cfg.act,
+                               score=cfg.router_score, bias=bias,
+                               routed_scale=cfg.routed_scale,
+                               balance=cfg.balance_loss)
     else:
-        ffn_out, aux = mlp(p["mlp"], h, cfg.act), jnp.zeros((), jnp.float32)
+        ffn_out, aux = mlp(p["mlp"], h, cfg.act), {}
     x = x + ffn_out
     x = shard(x, "batch", "seq", "embed")
     return x, new_cache, aux
@@ -211,7 +236,7 @@ def apply_attn_block(p, x, cfg: ModelConfig, *, positions, mode="train",
 def apply_rglru_block(p, x, cfg: ModelConfig, *, mode="train", state=None):
     w = cfg.lru_width or cfg.d_model
     del w
-    h = norm(p["norm1"], x, cfg.rms_norm)
+    h = norm(p["norm1"], x, cfg.rms_norm, cfg.rms_eps)
     gate = jax.nn.gelu(jnp.einsum(
         "bsd,dw->bsw", h, p["w_gate"],
         preferred_element_type=interior_pref()).astype(jnp.float32)
@@ -239,16 +264,16 @@ def apply_rglru_block(p, x, cfg: ModelConfig, *, mode="train", state=None):
                    preferred_element_type=interior_pref()).astype(x.dtype)
     x = x + y
     x = shard(x, "batch", "seq", "embed")
-    x = x + mlp(p["mlp"], norm(p["norm2"], x, cfg.rms_norm), cfg.act)
+    x = x + mlp(p["mlp"], norm(p["norm2"], x, cfg.rms_norm, cfg.rms_eps), cfg.act)
     x = shard(x, "batch", "seq", "embed")
-    return x, new_state, jnp.zeros((), jnp.float32)
+    return x, new_state, {}
 
 
 def apply_mlstm_block(p, x, cfg: ModelConfig, *, mode="train", state=None):
     d, nh = cfg.d_model, cfg.n_heads
     di = 2 * d
     dh = di // nh
-    h = norm(p["norm"], x, cfg.rms_norm)
+    h = norm(p["norm"], x, cfg.rms_norm, cfg.rms_eps)
     u = jnp.einsum("bsd,de->bse", h, p["wu"],
                    preferred_element_type=interior_pref()).astype(x.dtype)
     g = jnp.einsum("bsd,de->bse", h, p["wg"],
@@ -286,13 +311,13 @@ def apply_mlstm_block(p, x, cfg: ModelConfig, *, mode="train", state=None):
     y = ((hout * jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype)) @ p["wo"])
     x = x + y.astype(x.dtype)
     x = shard(x, "batch", "seq", "embed")
-    return x, new_state, jnp.zeros((), jnp.float32)
+    return x, new_state, {}
 
 
 def apply_slstm_block(p, x, cfg: ModelConfig, *, mode="train", state=None):
     d, nh = cfg.d_model, cfg.n_heads
     dh = d // nh
-    h = norm(p["norm"], x, cfg.rms_norm)
+    h = norm(p["norm"], x, cfg.rms_norm, cfg.rms_eps)
     if mode == "decode":
         c, conv_state = causal_conv_step(p["conv"], h[:, 0], state["conv"])
         c = jax.nn.silu(c.astype(jnp.float32)).astype(x.dtype)
@@ -327,14 +352,14 @@ def apply_slstm_block(p, x, cfg: ModelConfig, *, mode="train", state=None):
     x = x + hout
     x = x + mlp(p["ffn"], x, "silu")
     x = shard(x, "batch", "seq", "embed")
-    return x, new_state, jnp.zeros((), jnp.float32)
+    return x, new_state, {}
 
 
 def apply_block(p, x, cfg: ModelConfig, kind: str, *, positions=None,
-                mode="train", state=None, cache_len=None):
+                mode="train", state=None, cache_len=None, bias=None):
     if kind == "attn":
         return apply_attn_block(p, x, cfg, positions=positions, mode=mode,
-                                state=state, cache_len=cache_len)
+                                state=state, cache_len=cache_len, bias=bias)
     if kind == "rglru":
         return apply_rglru_block(p, x, cfg, mode=mode, state=state)
     if kind == "mlstm":
@@ -354,18 +379,27 @@ def _stackable(cfg: ModelConfig) -> bool:
 
 
 def def_stack(cfg: ModelConfig):
-    """Def-tree for the full stack of decoder blocks."""
+    """Def-tree for the full stack of decoder blocks: ``{"scan": stacked}``
+    (with ``"lead"``, a list of the leading dense layers, in front), or
+    ``{"layers": [...]}`` unrolled."""
+    n_dense = cfg.n_dense_layers
     if _stackable(cfg):
         one = def_block(cfg, "attn")
 
         def add_layer_axis(d: prm.ParamDef) -> prm.ParamDef:
-            return prm.ParamDef((cfg.n_layers,) + tuple(d.shape),
+            return prm.ParamDef((cfg.n_layers - n_dense,) + tuple(d.shape),
                                 ("layers",) + tuple(d.axes),
                                 init=d.init, scale=d.scale, dtype=d.dtype)
 
-        return {"scan": jax.tree.map(add_layer_axis, one, is_leaf=prm.is_def)}
+        out = {"scan": jax.tree.map(add_layer_axis, one, is_leaf=prm.is_def)}
+        if n_dense:
+            out["lead"] = [def_attn_block(cfg, moe=False)
+                           for _ in range(n_dense)]
+        return out
     pattern = cfg.pattern_for_layers()
-    return {"layers": [def_block(cfg, k) for k in pattern]}
+    return {"layers": [
+        def_attn_block(cfg, moe=cfg.is_moe and i >= n_dense) if k == "attn"
+        else def_block(cfg, k) for i, k in enumerate(pattern)]}
 
 
 def _remat_wrap(fn, cfg: ModelConfig):
@@ -377,44 +411,71 @@ def _remat_wrap(fn, cfg: ModelConfig):
 
 
 def stack_apply(p, x, cfg: ModelConfig, *, positions=None, mode="train",
-                states=None, cache_len=None):
-    """Run all decoder blocks. Returns (x, new_states, total_aux).
+                states=None, cache_len=None, buffers=None):
+    """Run all decoder blocks. Returns (x, new_states, aux), aux =
+    {"balance": summed balance loss (), and for MoE models "load": rows
+    routed to each expert, (MoE layers, n_experts)}.
 
     ``states`` is a list (unrolled) or stacked pytree (scan) of block states,
-    or None for train mode.
+    or None for train mode. ``buffers["router_bias"]`` (MoE layers,
+    n_experts) holds the routers' correction biases, where the model has
+    them.
     """
+    if mode != "train" and (cfg.is_mla or cfg.n_dense_layers):
+        raise NotImplementedError(
+            f"{cfg.name}: {mode} is built for uniform GQA stacks only; "
+            f"latent attention runs in training")
+    biases = (buffers or {}).get("router_bias")
+    balance = jnp.zeros((), jnp.float32)
     if _stackable(cfg):
+        for lead_p in p.get("lead", []):  # dense: no balance loss
+            def lead(h, lp):
+                return apply_attn_block(lp, h, cfg, positions=positions)[0]
+
+            x = _remat_wrap(lead, cfg)(x, lead_p)
+
         def body(carry, xs):
-            h, aux = carry
-            layer_p, layer_state = xs if mode == "decode" else (xs, None)
+            h, bal = carry
+            layer_p, layer_state, bias = xs
             h, new_state, a = apply_attn_block(
                 layer_p, h, cfg, positions=positions, mode=mode,
-                state=layer_state, cache_len=cache_len)
-            return (h, aux + a), new_state
+                state=layer_state, cache_len=cache_len, bias=bias)
+            return (h, bal + a.get("balance", 0.0)), (new_state, a.get("load"))
 
         body = _remat_wrap(body, cfg) if mode == "train" else body
-        xs = (p["scan"], states) if mode == "decode" else p["scan"]
-        (x, aux), new_states = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), xs)
+        xs = (p["scan"], states if mode == "decode" else None, biases)
+        (x, balance), (new_states, load) = jax.lax.scan(
+            body, (x, balance), xs)
         if mode == "train":
             new_states = None
+        aux = {"balance": balance}
+        if load is not None:
+            aux["load"] = load
         return x, new_states, aux
 
-    aux = jnp.zeros((), jnp.float32)
     pattern = cfg.pattern_for_layers()
-    new_states = []
+    new_states, loads = [], []
     for i, kind in enumerate(pattern):
         st = states[i] if states is not None else None
+        bias = None
+        if biases is not None and i >= cfg.n_dense_layers:
+            bias = biases[i - cfg.n_dense_layers]
 
-        def one(h, layer_p, st=st, kind=kind):
+        def one(h, layer_p, st=st, kind=kind, bias=bias):
             return apply_block(layer_p, h, cfg, kind, positions=positions,
-                               mode=mode, state=st, cache_len=cache_len)
+                               mode=mode, state=st, cache_len=cache_len,
+                               bias=bias)
 
         if mode == "train":
             one = _remat_wrap(one, cfg)
         x, ns, a = one(x, p["layers"][i])
         new_states.append(ns)
-        aux = aux + a
+        balance = balance + a.get("balance", 0.0)
+        if "load" in a:
+            loads.append(a["load"])
+    aux = {"balance": balance}
+    if loads:
+        aux["load"] = jnp.stack(loads)
     return x, new_states if states is not None or mode == "prefill" else None, aux
 
 

@@ -75,8 +75,9 @@ def def_norm(d, rms=True):
     return def_rmsnorm(d) if rms else def_layernorm(d)
 
 
-def norm(p, x, rms=True):
-    return rmsnorm(p, x) if rms else layernorm(p, x)
+def norm(p, x, rms=True, eps=1e-6):
+    """RMSNorm with ``eps`` (the configuration's), or LayerNorm."""
+    return rmsnorm(p, x, eps) if rms else layernorm(p, x)
 
 
 # Per-head norm used by qk-norm archs (qwen3, chameleon): normalizes head_dim.

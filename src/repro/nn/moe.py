@@ -1,229 +1,250 @@
-"""Mixture-of-Experts FFN with top-k routing.
+"""Mixture-of-experts FFN: top-k routing over all experts, dropless, for
+the experts this program holds.
 
-Two execution paths, same math:
+* ``moe_ffn_local`` — one device's part of the layer. It holds
+  ``e_local`` experts from ``e_start`` and routes every token over all
+  ``n_experts``; it returns the held experts' part of the result for the
+  tokens routed to them, plus the shared experts, which every holder
+  computes alike. On one chip of an expert-parallel deployment that is
+  the chip's share; holding every expert, it is the whole layer.
+* ``moe_ffn`` — under a mesh: the local path in ``jax.shard_map``, with
+  the held experts sharded over the ``model`` axis (their parts summed by
+  one ``psum``) or, where they do not divide it, token-parallel.
 
-* ``moe_ffn_local`` — single-device reference: local sort-based dispatch into
-  per-expert capacity buffers + ``jax.lax.ragged_dot`` grouped matmul. FLOPs
-  scale with *active* (routed) tokens, never with ``n_experts x tokens``.
-* ``moe_ffn`` — distributed: the local path wrapped in ``jax.shard_map`` over
-  the DP mesh axes with experts sharded over the ``model`` axis (expert
-  parallelism). Each model shard gathers only the rows routed to *its*
-  experts (token activations are replicated across the model axis at MoE
-  block entry, so no all-to-all is needed); per-shard contributions are
-  combined with a single ``psum`` over ``model`` — the same collective cost
-  as a Megatron TP MLP. Dispatch uses a *local* sort per DP shard, avoiding
-  GSPMD's cross-device bitonic sort entirely.
+No token is dropped. Dispatch sorts the (token, choice) rows by held
+expert into one buffer of ``tokens * top_k`` rows, the worst case, and
+the grouped matmul (``kernels.ops.expert_ffn``) computes only the rows
+routed: on a TPU its grid follows the tiles actually routed, so the
+static buffer costs memory and not compute. Rows for experts held
+elsewhere sort last and are neither computed nor combined.
 
-Token dropping follows GShard/Switch capacity semantics: per-expert capacity
-C = ceil(T_local * top_k / n_experts * capacity_factor); overflow rows are
-dropped (contribute zero, weight renormalization optional off).
+Routing is softmax top-k (weights renormalised over the k) or, for
+DeepSeek-V3-style layers, sigmoid scores whose top-k is chosen on score
+plus a correction bias that gradients do not train (``router_bias``,
+updated by ``models.steps``); the weights are the chosen raw scores,
+normalised and scaled by ``routed_scale``.
+
+A program that holds a share of the experts sees the outputs of its own
+experts only, so its loss's gradient through the routing weights would
+turn the router towards or away from the held experts as a whole,
+against those held elsewhere, which the whole layer's gradient does not:
+at random weights it starves the held experts within tens of steps. In a
+share the routing weights therefore pass no gradient back, to the router
+or through it to the layer's input (``route``'s ``share``); the balance
+loss and the correction bias still train and steer the router. The
+forward values do not change.
 """
 
 from __future__ import annotations
 
-import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.ops import expert_ffn
 from repro.nn import params as prm
-from repro.nn.layers import activation
+from repro.nn.mlp import def_mlp, mlp
 from repro.parallel import current_env
 
 
-def def_moe(d_model, n_experts, moe_d_ff, top_k, act="silu"):
-    del top_k, act
-    return {
+def def_moe(d_model, n_experts, moe_d_ff, *, e_local: Optional[int] = None,
+            shared_d_ff: int = 0):
+    e = e_local or n_experts
+    d = {
         "router": prm.matrix(d_model, n_experts, "embed", "experts",
                              dtype="float32"),
-        "up": prm.ParamDef((n_experts, d_model, moe_d_ff),
+        "up": prm.ParamDef((e, d_model, moe_d_ff),
                            ("experts", "embed", "expert_mlp"), init="scaled_fan_in"),
-        "gate": prm.ParamDef((n_experts, d_model, moe_d_ff),
+        "gate": prm.ParamDef((e, d_model, moe_d_ff),
                              ("experts", "embed", "expert_mlp"), init="scaled_fan_in"),
-        "down": prm.ParamDef((n_experts, moe_d_ff, d_model),
+        "down": prm.ParamDef((e, moe_d_ff, d_model),
                              ("experts", "expert_mlp", "embed"), init="scaled_fan_in"),
     }
+    if shared_d_ff:
+        d["shared"] = def_mlp(d_model, shared_d_ff)
+    return d
 
 
-def capacity(t_local: int, top_k: int, n_experts: int, factor: float,
-             min_capacity: int = 4) -> int:
-    c = math.ceil(t_local * top_k / n_experts * factor)
-    return max(min(max(c, min_capacity), t_local * top_k), 1)
+def route(p_router, x, *, top_k: int, score: str = "softmax", bias=None,
+          routed_scale: float = 1.0, balance: str = "switch",
+          share: bool = False):
+    """x: (B, S, d) → weights (B*S, k) fp32, experts (B*S, k) int32, the
+    balance loss () and each expert's routed rows (n_experts,) fp32.
 
+    ``share``: the program holds a share of the experts; the weights then
+    pass no gradient back.
 
-def router_topk(p_router, x, top_k: int):
-    """x: (T, d) → weights (T, k) fp32 (softmax over the selected k),
-    indices (T, k) int32, plus load-balancing aux loss (Switch-style)."""
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), p_router)
-    probs = jax.nn.softmax(logits, axis=-1)
-    w, idx = jax.lax.top_k(probs, top_k)
-    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
-    # aux loss: n_experts * mean(frac_tokens_e * mean_prob_e)
-    n_experts = logits.shape[-1]
-    hard = jax.nn.one_hot(idx[:, 0], n_experts, dtype=jnp.float32)
-    aux = n_experts * jnp.mean(jnp.mean(hard, axis=0) * jnp.mean(probs, axis=0))
-    return w, idx, aux
-
-
-def _dispatch_indices(idx, n_experts: int, cap: int, e_start, e_local: int):
-    """Build the gather map for experts [e_start, e_start + e_local).
-
-    idx: (T, k) expert assignment. Returns:
-      src:  (e_local * cap,) int32 — source row in the flattened (T*k) stream
-            (T*k means "empty slot"),
-      sizes: (e_local,) int32 — valid rows per local expert (<= cap).
+    ``balance="switch"``: n_experts * mean(top-1 share * mean score).
+    ``balance="seq"``: DeepSeek-V3's per-sequence loss, the mean over
+    sequences of sum_i f_i * P_i, with f_i = n_experts / (k * S) times the
+    rows routed to expert i and P_i the mean of its normalised score.
     """
-    t, k = idx.shape
-    flat = idx.reshape(-1)  # (T*k,)
-    order = jnp.argsort(flat, stable=True)  # rows grouped by expert
-    sorted_e = flat[order]
-    # Position of each sorted row within its expert group.
-    counts = jnp.bincount(flat, length=n_experts)  # (E,)
-    starts = jnp.cumsum(counts) - counts
-    pos_in_e = jnp.arange(t * k) - starts[sorted_e]
-    # Keep only local experts and rows under capacity.
-    local_e = sorted_e - e_start
-    keep = (local_e >= 0) & (local_e < e_local) & (pos_in_e < cap)
-    dest = jnp.where(keep, local_e * cap + pos_in_e, e_local * cap)
-    src = jnp.full((e_local * cap + 1,), t * k, jnp.int32)
-    src = src.at[dest].set(order.astype(jnp.int32), mode="drop")[:-1]
-    local_counts = jax.lax.dynamic_slice_in_dim(counts, e_start, e_local)
-    sizes = jnp.minimum(local_counts, cap).astype(jnp.int32)
-    return src, sizes
+    b, s, d = x.shape
+    n_experts = p_router.shape[-1]
+    logits = jnp.einsum("td,de->te", x.reshape(-1, d).astype(jnp.float32),
+                        p_router)
+    if score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores if bias is None else scores + bias
+    _, idx = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * routed_scale
+    if share:
+        w = jax.lax.stop_gradient(w)
+    load = jnp.bincount(idx.reshape(-1), length=n_experts).astype(jnp.float32)
+    if balance == "seq":
+        chosen = jnp.sum(jax.nn.one_hot(idx, n_experts, dtype=jnp.float32),
+                         axis=1).reshape(b, s, n_experts)
+        f = jnp.sum(chosen, axis=1) * (n_experts / (top_k * s))
+        norm = scores / jnp.sum(scores, axis=-1, keepdims=True)
+        pm = jnp.mean(norm.reshape(b, s, n_experts), axis=1)
+        aux = jnp.mean(jnp.sum(f * pm, axis=-1))
+    else:
+        hard = jax.nn.one_hot(idx[:, 0], n_experts, dtype=jnp.float32)
+        aux = n_experts * jnp.mean(jnp.mean(hard, axis=0)
+                                   * jnp.mean(scores, axis=0))
+    return w, idx, aux, load
 
 
-def _expert_ffn(up, gate, down, rows, sizes, act="silu", impl="einsum"):
-    """Grouped expert FFN over capacity buffers.
-
-    rows: (E_local*C, d) grouped by expert (fixed capacity C per expert);
-    sizes: (E_local,) valid rows per expert (only used by the ragged path).
-
-    impl="einsum" (default): reshape to (E_local, C, d) and run batched
-    einsums — flops = E_local*C*d*f = active_tokens*capacity_factor, the
-    GShard/megablox-equivalent dense-buffer formulation (MXU-native tiles,
-    no dynamic shapes). impl="ragged": jax.lax.ragged_dot — equivalent math,
-    but decomposes into a dense per-expert loop on non-TPU backends (kept
-    for comparison; see EXPERIMENTS.md §Perf iteration 1).
-    """
-    fn = activation(act)
-    if impl == "ragged":
-        h_up = jax.lax.ragged_dot(rows, up, sizes)
-        h_gate = jax.lax.ragged_dot(rows, gate, sizes)
-        h = (fn(h_gate.astype(jnp.float32)) * h_up.astype(jnp.float32)
-             ).astype(rows.dtype)
-        return jax.lax.ragged_dot(h, down, sizes)
-    e_local = up.shape[0]
-    buf = rows.reshape(e_local, -1, rows.shape[-1])  # (E_local, C, d)
-    h_up = jnp.einsum("ecd,edf->ecf", buf, up)
-    h_gate = jnp.einsum("ecd,edf->ecf", buf, gate)
-    h = (fn(h_gate.astype(jnp.float32)) * h_up.astype(jnp.float32)
-         ).astype(rows.dtype)
-    out = jnp.einsum("ecf,efd->ecd", h, down)
-    return out.reshape(rows.shape[0], -1)
+@jax.custom_vjp
+def _permute(x, order, inv):
+    """x[order] for a permutation ``order`` whose inverse is ``inv``; the
+    gradient is a gather by ``inv`` and not a scatter-add."""
+    return x[order]
 
 
-def moe_ffn_local(p, x, *, top_k: int, capacity_factor: float = 1.25,
-                  act: str = "silu", e_start=0, e_local: Optional[int] = None):
-    """MoE FFN on local rows for experts [e_start, e_start+e_local).
-
-    x: (T, d). Returns (y (T, d), aux_loss ()). Caller combines shards.
-    """
-    t, d = x.shape
-    n_experts = p["router"].shape[-1]
-    e_local = n_experts if e_local is None else e_local
-    w, idx, aux = router_topk(p["router"], x, top_k)
-    cap = capacity(t, top_k, n_experts, capacity_factor)
-    src, sizes = _dispatch_indices(idx, n_experts, cap, e_start, e_local)
-    # Gather rows (empty slots read row 0 but are zero-weighted on combine).
-    safe_src = jnp.minimum(src, t * top_k - 1)
-    rows = x[safe_src // top_k]  # (e_local*cap, d)
-    out_rows = _expert_ffn(p["up"], p["gate"], p["down"], rows, sizes, act)
-    # Combine: scatter-add weighted expert outputs back to token rows.
-    w_flat = w.reshape(-1)  # (T*k,)
-    row_w = jnp.where(src < t * top_k, w_flat[safe_src], 0.0)  # (e_local*cap,)
-    contrib = out_rows.astype(jnp.float32) * row_w[:, None]
-    y = jnp.zeros((t + 1, d), jnp.float32)
-    y = y.at[jnp.where(src < t * top_k, safe_src // top_k, t)].add(contrib,
-                                                                   mode="drop")
-    return y[:t].astype(x.dtype), aux
+def _permute_fwd(x, order, inv):
+    return x[order], (order, inv)
 
 
-def moe_ffn(p, x, *, top_k: int, capacity_factor: float = 1.25, act: str = "silu"):
-    """Distributed MoE FFN. x: (B, S, d) → (B, S, d), aux ().
+def _permute_bwd(res, g):
+    order, inv = res
+    return g[inv], None, None
 
-    When no mesh env is active, falls back to the local path.
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+def moe_ffn_local(p, x, *, top_k: int, act: str = "silu", e_start=0,
+                  e_local: Optional[int] = None, score: str = "softmax",
+                  bias=None, routed_scale: float = 1.0,
+                  balance: str = "switch", share: bool = False):
+    """The held experts' part of the layer for x: (B, S, d), plus the
+    shared experts. Returns (y (B, S, d), aux) with aux = {"balance": (),
+    "load": (n_experts,) rows routed to each expert}. ``e_local`` defaults
+    to the experts ``p`` holds; the router spans all of them. ``share`` as
+    ``route``'s: the whole program holds a share of the experts (under a
+    mesh, each shard holds part of the program's)."""
+    b, s, d = x.shape
+    t = b * s
+    e_local = p["up"].shape[0] if e_local is None else e_local
+    with jax.named_scope("moe.route"):
+        w, idx, aux, load = route(p["router"], x, top_k=top_k, score=score,
+                                  bias=bias, routed_scale=routed_scale,
+                                  balance=balance, share=share)
+    with jax.named_scope("moe.dispatch"):
+        local = idx.reshape(-1) - e_start  # (T*k,)
+        held = (local >= 0) & (local < e_local)
+        key = jnp.where(held, local, e_local)  # the rest sort last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * top_k, dtype=jnp.int32))
+        sizes = jnp.bincount(key, length=e_local + 1)[:e_local].astype(
+            jnp.int32)
+        valid = (jnp.arange(t * top_k) < jnp.sum(sizes))[:, None]
+        rows = jnp.repeat(x.reshape(t, d), top_k, axis=0)
+        rows = jnp.where(valid, _permute(rows, order, inv), 0)
+    with jax.named_scope("moe.experts"):
+        out = expert_ffn(rows, p["up"], p["gate"], p["down"], sizes, act)
+    with jax.named_scope("moe.combine"):
+        out = _permute(jnp.where(valid, out, 0), inv, order)
+        wk = jnp.where(held, w.reshape(-1), 0.0).reshape(t, top_k, 1)
+        y = jnp.sum(out.reshape(t, top_k, d).astype(jnp.float32) * wk, axis=1)
+        y = y.reshape(b, s, d)
+        if "shared" in p:
+            y = y + mlp(p["shared"], x, act).astype(jnp.float32)
+    return y.astype(x.dtype), {"balance": aux, "load": load}
+
+
+def moe_ffn(p, x, *, top_k: int, act: str = "silu", **routing):
+    """The MoE layer on x: (B, S, d) → (B, S, d), aux as ``moe_ffn_local``.
+
+    Without an active mesh env this is the local path: this program's
+    experts (all of them, or a chip's share). ``routing`` passes
+    ``score``, ``bias``, ``routed_scale`` and ``balance`` on.
     """
     env = current_env()
     b, s, d = x.shape
+    held = p["up"].shape[0]
+    routing = dict(routing, share=held < p["router"].shape[-1])
 
     if not env.active:
-        y, aux = moe_ffn_local(p, x.reshape(-1, d), top_k=top_k,
-                               capacity_factor=capacity_factor, act=act)
-        return y.reshape(b, s, d), aux
+        return moe_ffn_local(p, x, top_k=top_k, act=act, **routing)
 
     mesh = env.mesh
     dp_axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
     model_ax = "model"
     n_model = mesh.shape[model_ax]
-    n_experts = p["router"].shape[-1]
-    # Experts shard over model when divisible (expert parallelism). When not
+    # The held experts (all of them, or this program's share from expert
+    # 0) shard over model when divisible (expert parallelism). When not
     # (granite: 40 experts on a 16-way axis), fall back to TOKEN-parallel
     # MoE: sequence sharded over the model axis, experts replicated — every
     # shard routes/computes only its own tokens, no collectives inside the
-    # block at all (EXPERIMENTS.md §Perf granite it.7).
-    ep = n_model if n_experts % n_model == 0 else 1
+    # block at all.
+    ep = n_model if held % n_model == 0 else 1
     token_parallel = ep == 1 and s % n_model == 0
-    e_local = n_experts // ep
+    e_local = held // ep
+    # the shared experts are every shard's alike: run once, outside
+    p = dict(p)
+    shared = p.pop("shared", None)
+    rep = jax.tree.map(lambda _: P(), p)
+    aux_spec = {"balance": P(), "load": P()}
+
+    def with_shared(y, aux):
+        if shared is not None:
+            y = y + mlp(shared, x, act)
+        return y, aux
 
     if token_parallel:
-        in_specs = (
-            {"router": P(), "up": P(), "gate": P(), "down": P()},
-            P(dp_axes, model_ax, None),
-        )
-        out_specs = (P(dp_axes, model_ax, None), P())
+        in_specs = (rep, P(dp_axes, model_ax, None))
+        out_specs = (P(dp_axes, model_ax, None), aux_spec)
 
         def tp_fn(p_loc, x_loc):
-            bl, sl, dl = x_loc.shape
-            y, aux = moe_ffn_local(p_loc, x_loc.reshape(-1, dl), top_k=top_k,
-                                   capacity_factor=capacity_factor, act=act)
-            aux = jax.lax.pmean(aux, dp_axes + (model_ax,))
-            return y.reshape(bl, sl, dl), aux
+            y, aux = moe_ffn_local(p_loc, x_loc, top_k=top_k, act=act,
+                                   **routing)
+            aux = {"balance": jax.lax.pmean(aux["balance"],
+                                            dp_axes + (model_ax,)),
+                   "load": jax.lax.psum(aux["load"], dp_axes + (model_ax,))}
+            return y, aux
 
-        return jax.shard_map(tp_fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)(p, x)
+        return with_shared(*jax.shard_map(
+            tp_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False)(p, x))
 
     expert_spec = P(model_ax) if ep > 1 else P()
     in_specs = (
-        {
-            "router": P(),
-            "up": expert_spec,
-            "gate": expert_spec,
-            "down": expert_spec,
-        },
+        dict(rep, up=expert_spec, gate=expert_spec, down=expert_spec),
         P(dp_axes, None, None),  # x: batch over DP, replicated over model
     )
-    out_specs = (P(dp_axes, None, None), P())
+    out_specs = (P(dp_axes, None, None), aux_spec)
 
     def shard_fn(p_loc, x_loc):
-        bl, sl, dl = x_loc.shape
         m_idx = jax.lax.axis_index(model_ax)
         e_start = (m_idx * e_local) if ep > 1 else 0
-        y, aux = moe_ffn_local(p_loc, x_loc.reshape(-1, dl), top_k=top_k,
-                               capacity_factor=capacity_factor, act=act,
-                               e_start=e_start, e_local=e_local)
+        y, aux = moe_ffn_local(p_loc, x_loc, top_k=top_k, act=act,
+                               e_start=e_start, e_local=e_local, **routing)
+        balance = aux["balance"]
         if ep > 1:
             y = jax.lax.psum(y, model_ax)
-            aux = jax.lax.pmean(aux, model_ax)
-        else:
-            # Experts replicated: every model shard computed the same thing.
-            y = y / 1.0
-        aux = jax.lax.pmean(aux, dp_axes)
-        return y.reshape(bl, sl, dl), aux
+            balance = jax.lax.pmean(balance, model_ax)
+        return y, {"balance": jax.lax.pmean(balance, dp_axes),
+                   "load": jax.lax.psum(aux["load"], dp_axes)}
 
-    y, aux = jax.shard_map(
+    return with_shared(*jax.shard_map(
         shard_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
-    )(p, x)
-    return y, aux
+    )(p, x))

@@ -46,6 +46,10 @@ def test_decode_step_smoke(arch, rng_key):
     cfg = get_tiny_config(arch)
     params = steps.init_params(cfg, rng_key)
     s_max = 32
+    if cfg.is_mla:  # no latent cache yet: decoding says so
+        with pytest.raises(NotImplementedError, match="latent attention"):
+            steps.decode_state(cfg, B, s_max)
+        return
     if cfg.is_encoder_decoder:
         frames = jax.random.normal(rng_key, (B, cfg.enc_seq, cfg.d_model),
                                    jnp.bfloat16)
@@ -95,6 +99,7 @@ def test_param_counts_match_reported_class():
         "xlstm-125m": (1.0e8, 1.8e8),
         "whisper-tiny": (2.5e7, 5e7),
         "granite-moe-3b-a800m": (2.6e9, 3.9e9),
+        "moonlight-16b-a3b": (1.5e10, 1.7e10),
     }
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()
@@ -113,10 +118,11 @@ def test_moe_local_flops_scale_with_topk_not_experts(rng_key):
 
     d, ff = 32, 64
     for n_experts in [4, 16]:
-        p = prm.materialize(rng_key, def_moe(d, n_experts, ff, 2), jnp.float32)
-        x = jax.random.normal(rng_key, (128, d))
+        p = prm.materialize(rng_key, def_moe(d, n_experts, ff), jnp.float32)
+        x = jax.random.normal(rng_key, (1, 128, d))
         y, aux = jax.jit(
             lambda p, x: moe_ffn_local(p, x, top_k=2))(p, x)
         assert y.shape == x.shape
         assert np.isfinite(np.asarray(y)).all()
-        assert float(aux) > 0
+        assert float(aux["balance"]) > 0
+        assert float(aux["load"].sum()) == 128 * 2

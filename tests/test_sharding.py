@@ -192,3 +192,61 @@ def test_elastic_restore_onto_different_mesh(env):
 
     np.testing.assert_allclose(float(m_b["loss"]), float(m_a2["loss"]),
                                rtol=2e-3)
+
+
+@needs_real_mesh
+@pytest.mark.parametrize("arch", ["moonlight-16b-a3b", "granite-moe-3b-a800m"])
+def test_sharded_moe_matches_the_local_layer(env, arch):
+    """Under the mesh the MoE layer stays dropless: expert-parallel
+    (moonlight's 8 experts over the 2-way model axis, the shared experts
+    added once) and token-parallel (granite's 5 experts) give the local
+    layer's output and routed rows."""
+    from repro.configs import get_tiny_config
+    from repro.nn import params as prm
+    from repro.nn.moe import def_moe, moe_ffn
+    from repro.parallel import use_env
+
+    cfg = get_tiny_config(arch).replace(e_local=0)
+    p = prm.materialize(jax.random.key(0), def_moe(
+        cfg.d_model, cfg.n_experts, cfg.moe_d_ff,
+        shared_d_ff=cfg.shared_d_ff), jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model))
+    kw = dict(top_k=cfg.top_k, score=cfg.router_score,
+              routed_scale=cfg.routed_scale, balance=cfg.balance_loss)
+    want, want_aux = jax.jit(lambda p, x: moe_ffn(p, x, **kw))(p, x)
+    with use_env(env):
+        got, aux = jax.jit(lambda p, x: moe_ffn(p, x, **kw))(p, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(aux["load"]),
+                                  np.asarray(want_aux["load"]))
+
+
+@needs_real_mesh
+def test_sharded_moe_holds_a_share_with_a_bias(env):
+    """A program holding a share of the experts (the tiny moonlight's 4 of
+    8, two on each shard of the 2-way model axis) and routing with a
+    correction bias gives the local layer's output and routed rows under
+    the mesh: rows routed to experts it does not hold stay out."""
+    from repro.configs import get_tiny_config
+    from repro.nn import params as prm
+    from repro.nn.moe import def_moe, moe_ffn
+    from repro.parallel import use_env
+
+    cfg = get_tiny_config("moonlight-16b-a3b")
+    assert 0 < cfg.experts_held < cfg.n_experts
+    p = prm.materialize(jax.random.key(0), def_moe(
+        cfg.d_model, cfg.n_experts, cfg.moe_d_ff, e_local=cfg.experts_held,
+        shared_d_ff=cfg.shared_d_ff), jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model))
+    bias = 0.1 * jax.random.normal(jax.random.key(2), (cfg.n_experts,))
+    kw = dict(top_k=cfg.top_k, score=cfg.router_score, bias=bias,
+              routed_scale=cfg.routed_scale, balance=cfg.balance_loss)
+    want, want_aux = jax.jit(lambda p, x: moe_ffn(p, x, **kw))(p, x)
+    with use_env(env):
+        got, aux = jax.jit(lambda p, x: moe_ffn(p, x, **kw))(p, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(aux["load"]),
+                                  np.asarray(want_aux["load"]))
+    assert float(jnp.sum(want_aux["load"][cfg.experts_held:])) > 0

@@ -28,6 +28,10 @@ V5E_HBM_BYTES = 16 * 10**9
 TRAIN_BATCH, TRAIN_SEQ = 4, 2048
 # and its serving requests: prompt 512, 32 generated tokens
 PROMPT, GEN = 512, 32
+# the MoE cell's job (bench/traffic/moe8k.json) and its cut of
+# moonlight-16b-a3b (bench/configs/moonlight-16b-a3b.json overrides)
+MOE_BATCH, MOE_SEQ = 2, 8192
+MOE_CUT = {"n_layers": 5, "e_local": 8, "vocab_size": 20480}
 
 
 @pytest.fixture(scope="module")
@@ -86,20 +90,27 @@ def test_rglru_kernel_at_recurrentgemma_width(one_chip, dtype):
     assert _has_kernel(rglru_scan_tpu.lower(ab, ab, h0).compile())
 
 
-def test_smollm_train_step_fits_one_chip(one_chip):
-    """The step as the platform's learner jits it (no donation): its
-    attention is the splash kernel, forward (run again under remat) and
-    backward, and the only loops left are the two layer scans."""
-    cfg = get_config("smollm-360m")
+def _learner_step(cfg, sharding, batch, seq):
+    """The train step as the platform's learner jits it (donated state),
+    compiled, and the bytes it needs on the device."""
     opt = adamw.AdamWConfig(total_steps=15)
-    state = _on(one_chip, steps.abstract_train_state(cfg))
-    tok = jax.ShapeDtypeStruct((TRAIN_BATCH, TRAIN_SEQ), jnp.int32,
-                               sharding=one_chip)
-    compiled = jax.jit(steps.make_train_step(cfg, opt)).lower(
+    state = _on(sharding, steps.abstract_train_state(cfg))
+    tok = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=sharding)
+    compiled = jax.jit(steps.make_train_step(cfg, opt),
+                       donate_argnums=(0,)).lower(
         state, {"tokens": tok, "labels": tok}).compile()
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    return compiled, need
+
+
+def test_smollm_train_step_fits_one_chip(one_chip):
+    """The step as the platform's learner jits it: its attention is the
+    splash kernel, forward (run again under remat) and backward, and the
+    only loops left are the two layer scans."""
+    compiled, need = _learner_step(get_config("smollm-360m"), one_chip,
+                                   TRAIN_BATCH, TRAIN_SEQ)
     assert need < V5E_HBM_BYTES, f"{need / 2**30:.2f} GiB"
     text = compiled.as_text()
     assert _splash_kernels(text) == {"splash_mqa_fwd_residuals",
@@ -128,3 +139,22 @@ def test_smollm_serve_steps_compile(one_chip, phase):
     if phase == "prefill":  # the kernel's forward over the prompt
         assert _splash_kernels(compiled.as_text()) == {
             "splash_mqa_fwd_no_residuals"}
+
+
+def test_moonlight_cut_train_step_fits_one_chip(one_chip):
+    """The MoE cell's donated step (batch 2 x 8192, 5 layers, 8 of 64
+    experts, a 20,480-row vocabulary slice): the donated state is aliased
+    to the new one, the whole fits 16 GB, latent attention runs the splash
+    kernels and the held experts the megablox grouped matmul, forward and
+    backward."""
+    cfg = get_config("moonlight-16b-a3b").replace(**MOE_CUT)
+    compiled, need = _learner_step(cfg, one_chip, MOE_BATCH, MOE_SEQ)
+    assert need < V5E_HBM_BYTES, f"{need / 2**30:.2f} GiB"
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes
+    text = compiled.as_text()
+    assert _splash_kernels(text) == {"splash_mqa_fwd_residuals",
+                                     "splash_mqa_dq_no_residuals",
+                                     "splash_mqa_dkv_no_residuals"}
+    assert re.search(r"%gmm\.\d+ = ", text)
+    assert re.search(r"%tgmm\.\d+ = ", text)
