@@ -334,7 +334,8 @@ class RealLearner:
 
 # the step's MoE metrics and the counters they add to, once a tick
 MOE_COUNTERS = {"moe_rows_held": "moe.rows_held",
-                "moe_rows_busiest": "moe.rows_busiest"}
+                "moe_rows_busiest": "moe.rows_busiest",
+                "moe_full_buffer": "moe.full_buffer"}
 
 
 def make_learner(ctx: LearnerContext):

@@ -60,11 +60,15 @@ def self_attention(q, k, v, *, causal=True, window=0, chunk=512):
     return jax.lax.platform_dependent(q, k, v, tpu=kernel, default=scan)
 
 
+# rows of one megablox tile
+GMM_ROW_TILE = 512
+
+
 def gmm_tiling(m: int, k: int, n: int) -> tuple:
-    """The megablox kernel's (m, k, n) tiles: 512 rows by 1024-wide
-    slices, or the whole dimension where it is smaller; partial tiles of
-    k and n are masked by the kernel."""
-    return min(512, m), min(1024, k), min(1024, n)
+    """The megablox kernel's (m, k, n) tiles: ``GMM_ROW_TILE`` rows by
+    1024-wide slices, or the whole dimension where it is smaller; partial
+    tiles of k and n are masked by the kernel."""
+    return min(GMM_ROW_TILE, m), min(1024, k), min(1024, n)
 
 
 def expert_ffn(rows, up, gate, down, sizes, act="silu", *,

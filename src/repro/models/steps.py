@@ -17,6 +17,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import encdec, lm
 from repro.nn import params as prm
 from repro.nn.blocks import init_stack_state
+from repro.nn.moe import dispatch_capacity
 from repro.optim import adamw
 
 
@@ -100,9 +101,11 @@ def update_router_bias(bias, load, rate: float):
 
 def make_train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig):
     """The step. Its metrics hold, for MoE models, ``moe_rows_held``: rows
-    routed to the experts this program holds, summed over layers, and
+    routed to the experts this program holds, summed over layers,
     ``moe_rows_busiest``: the busiest held expert's rows, summed over
-    layers."""
+    layers, and ``moe_full_buffer``: the layers whose held rows overflowed
+    the sized dispatch buffer (``nn.moe.dispatch_capacity``, at the
+    step's whole batch) and took the worst-case path."""
 
     def train_step(state: TrainState, batch):
         (loss, parts), grads = jax.value_and_grad(
@@ -115,6 +118,10 @@ def make_train_step(cfg: ModelConfig, opt_cfg: adamw.AdamWConfig):
             held = load[:, :cfg.experts_held]
             parts["moe_rows_held"] = jnp.sum(held)
             parts["moe_rows_busiest"] = jnp.sum(jnp.max(held, axis=-1))
+            cap = dispatch_capacity(batch["tokens"].size, cfg.top_k,
+                                    cfg.experts_held, cfg.n_experts)
+            parts["moe_full_buffer"] = jnp.sum(
+                jnp.sum(held, axis=-1) > cap).astype(jnp.int32)
             if "router_bias" in buffers:
                 buffers = dict(buffers, router_bias=update_router_bias(
                     buffers["router_bias"], load, cfg.router_bias_rate))

@@ -12,11 +12,18 @@ the experts this program holds.
   one ``psum``) or, where they do not divide it, token-parallel.
 
 No token is dropped. Dispatch sorts the (token, choice) rows by held
-expert into one buffer of ``tokens * top_k`` rows, the worst case, and
-the grouped matmul (``kernels.ops.expert_ffn``) computes only the rows
-routed: on a TPU its grid follows the tiles actually routed, so the
-static buffer costs memory and not compute. Rows for experts held
-elsewhere sort last and are neither computed nor combined.
+expert, and the grouped matmul (``kernels.ops.expert_ffn``) computes
+only the rows routed: on a TPU its grid follows the tiles actually
+routed. The buffer it reads is sized to the share of experts the
+program holds (``dispatch_capacity``): twice their even share of the
+``tokens * top_k`` rows, in whole row tiles. Where that is less than
+every row, the layer gathers only the held rows straight from the
+tokens and sums each token's rows, weighted, back into a float32
+output; a layer step whose held rows overflow the buffer takes the
+worst-case path instead (``lax.cond``), one buffer of ``tokens *
+top_k`` rows in which rows for experts held elsewhere sort last and are
+neither computed nor combined. Both compute the same function. Holding
+every expert, the buffer is the worst case and no condition is built.
 
 Routing is softmax top-k (weights renormalised over the k) or, for
 DeepSeek-V3-style layers, sigmoid scores whose top-k is chosen on score
@@ -37,13 +44,14 @@ forward values do not change.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.kernels.ops import expert_ffn
+from repro.kernels.ops import GMM_ROW_TILE, expert_ffn
 from repro.nn import params as prm
 from repro.nn.mlp import def_mlp, mlp
 from repro.parallel import current_env
@@ -129,6 +137,149 @@ def _permute_bwd(res, g):
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
+def dispatch_capacity(tokens: int, top_k: int, e_local: int,
+                      n_experts: int) -> int:
+    """Rows of the dispatch buffer: twice the held experts' even share of
+    the ``tokens * top_k`` rows, rounded up to whole grouped-matmul row
+    tiles, and at most all of them."""
+    rows = tokens * top_k
+    tiles = -(-2 * rows * e_local // (n_experts * GMM_ROW_TILE))
+    return min(rows, tiles * GMM_ROW_TILE)
+
+
+def _take(src, tok):
+    """src's rows at ``tok``, clipped to the last: a row whose ``tok``
+    lies past them reads a row it must not count (zero weight, or left
+    out of ``_sum``)."""
+    return jnp.take(src, tok, axis=0, mode="clip")
+
+
+def _sum(t: int, rows, tok, wts=None):
+    """(t, d) float32: each token's rows, weighted by ``wts`` where given,
+    summed; rows whose ``tok`` lies past ``t`` are left out."""
+    rows = rows.astype(jnp.float32)
+    if wts is not None:
+        rows = wts[:, None] * rows
+    return jnp.zeros((t, rows.shape[1]), jnp.float32).at[tok].add(
+        rows, mode="drop")
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _take_rows(t, src, tok):
+    """Take rows by token, from ``src`` of ``t`` rows: ``_take``. Its
+    transpose sums rows per token."""
+    return _take(src, tok)
+
+
+def _take_rows_fwd(t, src, tok):
+    return _take(src, tok), tok
+
+
+def _take_rows_bwd(t, tok, g):
+    return _sum(t, g, tok).astype(g.dtype), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _sum_rows(t, rows, tok, wts):
+    """Sum rows per token, weighted: ``_sum``. Its transpose takes rows by
+    token."""
+    return _sum(t, rows, tok, wts)
+
+
+def _sum_rows_fwd(t, rows, tok, wts):
+    return _sum(t, rows, tok, wts), (rows, tok, wts)
+
+
+def _sum_rows_bwd(t, res, g):
+    rows, tok, wts = res
+    g_rows = _take(g, tok)
+    return ((wts[:, None] * g_rows).astype(rows.dtype), None,
+            jnp.sum(g_rows * rows.astype(jnp.float32), axis=-1))
+
+
+_sum_rows.defvjp(_sum_rows_fwd, _sum_rows_bwd)
+
+
+def _worst_case(act, x2d, w, held, order, sizes, up, gate, down):
+    """The held experts' part of the layer, (T, d) float32, through one
+    buffer of every (token, choice) row: ``order`` sorts them by held
+    expert, the rest last."""
+    t, d = x2d.shape
+    n = order.shape[0]
+    top_k = n // t
+    with jax.named_scope("moe.dispatch"):
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        valid = (jnp.arange(n) < jnp.sum(sizes))[:, None]
+        rows = jnp.repeat(x2d, top_k, axis=0)
+        rows = jnp.where(valid, _permute(rows, order, inv), 0)
+    with jax.named_scope("moe.experts"):
+        out = expert_ffn(rows, up, gate, down, sizes, act)
+    with jax.named_scope("moe.combine"):
+        out = _permute(jnp.where(valid, out, 0), inv, order)
+        wk = jnp.where(held, w.reshape(-1), 0.0).reshape(t, top_k, 1)
+        return jnp.sum(out.reshape(t, top_k, d).astype(jnp.float32) * wk,
+                       axis=1)
+
+
+def _sized(cap, act, x2d, w, held, order, sizes, up, gate, down):
+    """``_worst_case`` through a buffer of ``cap`` rows, for held rows
+    that fit it: each sorted row's token is its (token, choice) row // k;
+    padding rows take the token past the last, so that their gradient
+    and their undefined outputs are summed into no token."""
+    del held  # the first sum(sizes) sorted rows are the held ones
+    t = x2d.shape[0]
+    top_k = order.shape[0] // t
+    with jax.named_scope("moe.dispatch"):
+        sel = order[:cap]
+        tok = jnp.where(jnp.arange(cap) < jnp.sum(sizes), sel // top_k, t)
+        rows = _take_rows(t, x2d, tok)
+    with jax.named_scope("moe.experts"):
+        out = expert_ffn(rows, up, gate, down, sizes, act)
+    with jax.named_scope("moe.combine"):
+        wk = jnp.where(tok < t, w[sel // top_k, sel % top_k], 0.0)
+        return _sum_rows(t, out, tok, wk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _routed(cap, act, share, *ops):
+    """``_sized`` where the held rows fit ``cap``, else ``_worst_case``.
+    The backward chooses again and runs the chosen path's own backward,
+    so neither branch holds the other's residuals."""
+    return _routed_fwd(cap, act, share, *ops)[0]
+
+
+def _paths(cap, act):
+    return (functools.partial(_sized, cap, act),
+            functools.partial(_worst_case, act))
+
+
+def _routed_fwd(cap, act, share, *ops):
+    fits = jnp.sum(ops[4]) <= cap  # ops[4]: the held experts' sizes
+    return jax.lax.cond(fits, *_paths(cap, act), *ops), ops
+
+
+def _routed_bwd(cap, act, share, ops, g):
+    def backward(path):
+        def run(g, x2d, w, held, order, sizes, *experts):
+            def f(x2d, w, *experts):
+                w = jax.lax.stop_gradient(w) if share else w
+                return path(x2d, w, held, order, sizes, *experts)
+            return jax.vjp(f, x2d, w, *experts)[1](g)
+        return run
+
+    fits = jnp.sum(ops[4]) <= cap
+    dx, dw, *d_experts = jax.lax.cond(
+        fits, *map(backward, _paths(cap, act)), g, *ops)
+    return (dx, dw, None, None, None, *d_experts)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
 def moe_ffn_local(p, x, *, top_k: int, act: str = "silu", e_start=0,
                   e_local: Optional[int] = None, score: str = "softmax",
                   bias=None, routed_scale: float = 1.0,
@@ -151,19 +302,18 @@ def moe_ffn_local(p, x, *, top_k: int, act: str = "silu", e_start=0,
         held = (local >= 0) & (local < e_local)
         key = jnp.where(held, local, e_local)  # the rest sort last
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv = jnp.zeros_like(order).at[order].set(
-            jnp.arange(t * top_k, dtype=jnp.int32))
         sizes = jnp.bincount(key, length=e_local + 1)[:e_local].astype(
             jnp.int32)
-        valid = (jnp.arange(t * top_k) < jnp.sum(sizes))[:, None]
-        rows = jnp.repeat(x.reshape(t, d), top_k, axis=0)
-        rows = jnp.where(valid, _permute(rows, order, inv), 0)
-    with jax.named_scope("moe.experts"):
-        out = expert_ffn(rows, p["up"], p["gate"], p["down"], sizes, act)
+    ops = (x.reshape(t, d), w, held, order, sizes,
+           p["up"], p["gate"], p["down"])
+    cap = dispatch_capacity(t, top_k, e_local, p["router"].shape[-1])
+    if cap < t * top_k:
+        # outside the scopes: the op that holds the branches is not
+        # counted on top of their ops
+        y = _routed(cap, act, share, *ops)
+    else:
+        y = _worst_case(act, *ops)
     with jax.named_scope("moe.combine"):
-        out = _permute(jnp.where(valid, out, 0), inv, order)
-        wk = jnp.where(held, w.reshape(-1), 0.0).reshape(t, top_k, 1)
-        y = jnp.sum(out.reshape(t, top_k, d).astype(jnp.float32) * wk, axis=1)
         y = y.reshape(b, s, d)
         if "shared" in p:
             y = y + mlp(p["shared"], x, act).astype(jnp.float32)

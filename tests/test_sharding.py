@@ -250,3 +250,40 @@ def test_sharded_moe_holds_a_share_with_a_bias(env):
     np.testing.assert_array_equal(np.asarray(aux["load"]),
                                   np.asarray(want_aux["load"]))
     assert float(jnp.sum(want_aux["load"][cfg.experts_held:])) > 0
+
+
+@needs_real_mesh
+def test_sharded_share_takes_the_sized_buffer(env):
+    """Each shard of the 2-way model axis holds 2 of the tiny moonlight's
+    8 experts and 2,048 tokens of the 4 x 1,024 batch: its dispatch
+    buffer is sized to its share (3,072 of 6,144 rows) and the layer
+    still gives the local layer's output and routed rows, forward and
+    backward."""
+    from repro.configs import get_tiny_config
+    from repro.nn import params as prm
+    from repro.nn.moe import def_moe, dispatch_capacity, moe_ffn
+    from repro.parallel import use_env
+
+    cfg = get_tiny_config("moonlight-16b-a3b")
+    assert dispatch_capacity(2 * 1024, cfg.top_k, 2, cfg.n_experts) == 3072
+    p = prm.materialize(jax.random.key(0), def_moe(
+        cfg.d_model, cfg.n_experts, cfg.moe_d_ff, e_local=cfg.experts_held,
+        shared_d_ff=cfg.shared_d_ff), jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (4, 1024, cfg.d_model))
+    kw = dict(top_k=cfg.top_k, score=cfg.router_score,
+              routed_scale=cfg.routed_scale, balance=cfg.balance_loss)
+
+    def loss(p, x):
+        y, aux = moe_ffn(p, x, **kw)
+        return jnp.sum(y ** 2), (y, aux["load"])
+
+    run = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+    (_, (want, want_load)), want_g = run(p, x)
+    with use_env(env):
+        (_, (got, load)), got_g = run(p, x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+    for g, w in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                   atol=1e-5 * float(jnp.max(jnp.abs(w))))

@@ -12,6 +12,7 @@ worker imports this file.
 import functools
 import re
 
+import _hlo
 import jax
 import jax.numpy as jnp
 import pytest
@@ -146,7 +147,10 @@ def test_moonlight_cut_train_step_fits_one_chip(one_chip):
     experts, a 20,480-row vocabulary slice): the donated state is aliased
     to the new one, the whole fits 16 GB, latent attention runs the splash
     kernels and the held experts the megablox grouped matmul, forward and
-    backward."""
+    backward. The held rows' buffer is sized to the share (24,576 of
+    98,304 rows) behind a condition, forward and backward, that lies
+    outside the MoE scopes; its sized branch has no worst-case row
+    buffer and no (tokens, top_k, d_model) combine."""
     cfg = get_config("moonlight-16b-a3b").replace(**MOE_CUT)
     compiled, need = _learner_step(cfg, one_chip, MOE_BATCH, MOE_SEQ)
     assert need < V5E_HBM_BYTES, f"{need / 2**30:.2f} GiB"
@@ -158,3 +162,11 @@ def test_moonlight_cut_train_step_fits_one_chip(one_chip):
                                      "splash_mqa_dkv_no_residuals"}
     assert re.search(r"%gmm\.\d+ = ", text)
     assert re.search(r"%tgmm\.\d+ = ", text)
+    rows, top_k = MOE_BATCH * MOE_SEQ, cfg.top_k
+    worst = (f"[{rows * top_k},", f"[{rows},{top_k},{cfg.d_model}]")
+    conds = _hlo.conditionals(text)
+    assert len(conds) == 2
+    for line, (full, sized) in conds:  # branch 1: the held rows fit
+        assert "moe." not in _hlo.op_name(line)
+        assert not any(w in x for x in sized for w in worst)
+        assert any(worst[0] in x for x in full)
